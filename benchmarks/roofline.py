@@ -19,7 +19,9 @@ Two row families:
     fused   the megakernel (``repro.kernels.fused_agg``): two input
             sweeps, one (d,) write — nothing else touches HBM.
 
-  Step-time = max(bytes / HBM_BW, flops / PEAK) on v5e constants; all
+  Step-time = max(bytes / HBM bandwidth, flops / peak) on the published
+  peaks of the target chip (``repro.launch.device.peaks``; a v5e for the
+  modeled rows, the attached chip's own kind for the measured ones); all
   three backends are memory-bound at production d, so the byte ratio is
   the speedup.  Wall-clock rows are measured only on TPU — off-TPU the
   Pallas kernels run in the pure-Python interpreter, so the rows emit
@@ -37,12 +39,9 @@ import os
 from typing import Dict, Optional, Sequence
 
 from benchmarks.common import emit
+from repro.launch.device import PRODUCTION_KIND, peaks
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
-
-# v5e per-chip peaks (same constants as repro.launch.dryrun's roofline)
-PEAK_FLOPS = 197e12   # bf16 MXU
-HBM_BW = 819e9        # bytes/s
 
 #: production aggregation shape: the paper's Fig 4-6 committee
 AGG_N, AGG_F = 39, 9
@@ -141,13 +140,15 @@ def main_agg_backends(ds: Sequence[int] = (1_000_000, 100_000_000),
     import jax
 
     n, f = AGG_N, AGG_F
+    on_tpu = measure and jax.default_backend() == "tpu"
+    chip = peaks(jax.devices()[0].device_kind if on_tpu else PRODUCTION_KIND)
     for d in ds:
         ref_us: Dict[str, float] = {}
         for backend in ("xla", "pallas", "fused"):
             items = _agg_bytes(backend, n, f, d)
             total = sum(items.values())
-            mem_s = total / HBM_BW
-            comp_s = _agg_flops(n, d) / PEAK_FLOPS
+            mem_s = total / chip.hbm_bw
+            comp_s = _agg_flops(n, d) / chip.flops
             us = 1e6 * max(mem_s, comp_s)
             ref_us[backend] = us
             itemized = ";".join(f"{k}={v / d:.0f}d" for k, v in
@@ -160,7 +161,6 @@ def main_agg_backends(ds: Sequence[int] = (1_000_000, 100_000_000),
                  backend)
         if not measure:
             continue
-        on_tpu = jax.default_backend() == "tpu"
         if not on_tpu:
             for backend in ("xla", "pallas", "fused"):
                 emit(f"roofline/agg.n{n}.f{f}.d{d}.measured", 0,

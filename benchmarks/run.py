@@ -12,6 +12,9 @@ the environment (jax version, backend, device/host counts, python) and
 the effective seed — enough to pin down *which* machine and RNG stream
 produced a row when two runs disagree.  ``--no-artifacts`` disables the
 writes (e.g. on read-only checkouts).
+
+A bench that raises is reported as a ``<name>/ERROR`` row and the others
+still run; the harness then exits non-zero, naming every failed bench.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import pathlib
 import platform
 import sys
 import time
+import traceback
 
 ARTIFACTS = pathlib.Path(__file__).resolve().parent / "artifacts"
 
@@ -85,6 +89,9 @@ def main() -> None:
                     help="skip the BENCH_<name>.json artifact writes")
     args = ap.parse_args()
 
+    from repro.launch.device import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (fig2_mnist_attack, fig3_cifar_attack,
                             fig45_bulyan_defense, fig6_bulyan_cost,
                             gar_async, gar_reputation, gar_throughput,
@@ -119,6 +126,7 @@ def main() -> None:
         ("roofline", lambda: roofline.main()),
     ]
     env = bench_env()
+    failed = []
     print("name,backend,us_per_call,derived")
     for name, fn in benches:
         if args.only and args.only != name:
@@ -131,8 +139,10 @@ def main() -> None:
         try:
             with contextlib.redirect_stdout(buf):
                 fn()
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # keep the harness going; exit code below
             err = f"{type(e).__name__}:{e}"
+            failed.append(name)
+            traceback.print_exc()
         captured = buf.getvalue()
         sys.stdout.write(captured)
         if err:
@@ -144,6 +154,8 @@ def main() -> None:
             extra = {"error": err} if err else None
             write_artifact(name, rows, seed=args.seed, env=env,
                            wall_s=wall, extra=extra)
+    if failed:
+        sys.exit(f"benches failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.data import ByzantineBatcher
 from repro.data.synthetic import mnist_like
+from repro.launch.device import enable_compile_cache
 from repro.models import simple
 from repro.optim import fading_lr, get_optimizer
 from repro.training import (AsyncByzantineTrainer, ByzantineSpec,
@@ -91,6 +92,7 @@ def main():
                          "with this staleness bound (stale-replay vs "
                          "stale-krum/stale-bulyan)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.async_tau is not None:
         main_async(args)

@@ -10,7 +10,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import get_attack, get_gar
+from repro.launch.device import enable_compile_cache
 
+enable_compile_cache()
 n_honest, f, d = 12, 3, 10_000
 key = jax.random.PRNGKey(0)
 

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.agg import AggSpec, quorum
 from repro.dist.serve_robust import poison_replicas, replicate_params
+from repro.launch.device import enable_compile_cache
 from repro.models import init_model
 from repro.models.config import ModelConfig
 from repro.serving import Request, ServingEngine
@@ -132,6 +133,7 @@ def main():
     ap.add_argument("--jitter", type=float, default=1e-3,
                     help="honest replica jitter (independent fine-tunes)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.ensemble > 0:
         main_ensemble(args)
     else:

@@ -4,13 +4,15 @@ Bulyan(Krum) aggregation, with checkpointing.
 
     PYTHONPATH=src python examples/train_llm.py --steps 200
 
-The model is a 12-layer, d=768 llama3-style decoder (~100M params).
-NOTE on this 1-core container a 100M Byzantine step (7 worker grads +
-in-graph attack + distributed Bulyan) takes ~60 s; pass --d-model 384
---steps 150 for a ~25M quick run with identical mechanics.  One training step is the full production path: per-worker
-gradients -> in-graph omniscient attack -> distributed Bulyan -> AdamW.
-On the 256-chip mesh this exact step function is what the dry-run lowers;
-here it runs on CPU with n = 7 workers (f = 1).
+The model is a 12-layer, d=768 llama3-style decoder (~100M params) with
+heads derived from ``--d-model`` (d/64 heads), so it cannot express a
+published configuration's widths; ``chip_smoke.py`` drives the same step
+at ``llama3_2_3b``'s widths.  One training step is the full production
+path: per-worker gradients -> in-graph omniscient attack -> distributed
+Bulyan -> AdamW, with n = 7 workers (f = 1).  It runs on whatever backend
+JAX finds; on a CPU a 100M step takes about a minute, so pass
+``--d-model 384 --steps 150`` there for a ~25M run with identical
+mechanics.
 """
 import argparse
 import os
@@ -24,6 +26,7 @@ from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.data.synthetic import lm_batches
 from repro.dist.train import (DistByzantineSpec, init_agg_state,
                               make_train_step)
+from repro.launch.device import enable_compile_cache
 from repro.models import init_model
 from repro.models.config import ModelConfig
 from repro.optim import get_optimizer
@@ -57,6 +60,7 @@ def main():
     ap.add_argument("--ckpt", default="artifacts/llm_ckpt")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     import dataclasses
     cfg = dataclasses.replace(model_100m(), d_model=args.d_model,

@@ -125,7 +125,7 @@ def select_indices(grads: jnp.ndarray, f: int, base: str = "krum",
 
 
 def coordinate_phase(selected: jnp.ndarray, f: int) -> jnp.ndarray:
-    """Phase 2 on a (theta, d) stack: per-coordinate average of the beta
+    """Phase 2 on a (theta, ...) stack: per-coordinate average of the beta
     values closest to the coordinate-wise median.
 
     Key structural fact (reused by the Pallas kernel): after sorting each
@@ -139,19 +139,19 @@ def coordinate_phase(selected: jnp.ndarray, f: int) -> jnp.ndarray:
     if beta < 1:
         raise ValueError(
             f"beta = theta - 2f must be >= 1 (theta={theta}, f={f})")
-    s = jnp.sort(selected, axis=0)  # (theta, d)
+    s = jnp.sort(selected, axis=0)  # (theta, ...)
     med = s[(theta - 1) // 2]       # 1-D medoid: lower-middle of sorted vals
     if beta == theta:
         return jnp.mean(s, axis=0)
-    absdev = jnp.abs(s - med[None, :])
+    absdev = jnp.abs(s - med[None])
     zeros = jnp.zeros_like(s[:1])
     cd = jnp.concatenate([zeros, jnp.cumsum(absdev, axis=0)], axis=0)
     cv = jnp.concatenate([zeros, jnp.cumsum(s, axis=0)], axis=0)
     n_win = theta - beta + 1
-    win_dev = cd[beta:] - cd[:n_win]  # (n_win, d): sum |x - med| per window
-    win_sum = cv[beta:] - cv[:n_win]  # (n_win, d): sum x per window
-    w = jnp.argmin(win_dev, axis=0)   # (d,)
-    best = jnp.take_along_axis(win_sum, w[None, :], axis=0)[0]
+    win_dev = cd[beta:] - cd[:n_win]  # (n_win, ...): sum |x - med| per window
+    win_sum = cv[beta:] - cv[:n_win]  # (n_win, ...): sum x per window
+    w = jnp.argmin(win_dev, axis=0)   # (...)
+    best = jnp.take_along_axis(win_sum, w[None], axis=0)[0]
     return best / beta
 
 

@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core.bulyan import coordinate_phase
 from repro.kernels.pairwise_gram import (finalize_dists,
                                          pairwise_gram_partial,
                                          pairwise_gram_tree)
@@ -179,8 +180,6 @@ def _pallas_sharded_dists(tree: Any, mesh, *, block_d: int,
     their whole partial, so those partials must stay *out* of the psum —
     summing them post-reduction instead of multiplying them by the axis
     size."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.dist.sharding import gram_pspec
 
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
@@ -206,8 +205,8 @@ def _pallas_sharded_dists(tree: Any, mesh, *, block_d: int,
             sharded = jax.lax.psum(sharded, "model")
         return sharded + replicated
 
-    mapped = shard_map(local_partials, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(local_partials, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(), check_vma=False)
     return finalize_dists(mapped(*leaves))
 
 
@@ -268,29 +267,6 @@ def pairwise_sq_dists_tree(tree: Any, compute_dtype=jnp.float32, *,
 # coordinate phase over arbitrary trailing dims
 # ---------------------------------------------------------------------------
 
-def _phase_nd(selected: jnp.ndarray, f: int) -> jnp.ndarray:
-    """Bulyan phase 2 on a (theta, ...) stack, axis-0 vectorized over all
-    trailing dims.  Identical windowed algorithm to
-    ``repro.core.bulyan.coordinate_phase`` (see there for the contiguous-
-    window argument)."""
-    theta = selected.shape[0]
-    beta = theta - 2 * f
-    s = jnp.sort(selected, axis=0)
-    if beta == theta:
-        return jnp.mean(s, axis=0)
-    med = s[(theta - 1) // 2]
-    absdev = jnp.abs(s - med[None])
-    zeros = jnp.zeros_like(s[:1])
-    cd = jnp.concatenate([zeros, jnp.cumsum(absdev, axis=0)], axis=0)
-    cv = jnp.concatenate([zeros, jnp.cumsum(s, axis=0)], axis=0)
-    n_win = theta - beta + 1
-    win_dev = cd[beta:] - cd[:n_win]
-    win_sum = cv[beta:] - cv[:n_win]
-    w = jnp.argmin(win_dev, axis=0)
-    best = jnp.take_along_axis(win_sum, w[None], axis=0)[0]
-    return best / beta
-
-
 def coordinate_phase_nd(selected: jnp.ndarray, f: int,
                         window: Optional[int] = None) -> jnp.ndarray:
     """Bulyan's coordinate-wise phase over arbitrary trailing dims.
@@ -316,9 +292,9 @@ def coordinate_phase_nd(selected: jnp.ndarray, f: int,
     d = math.prod(trailing)
     with named_span("agg/coordinate"):
         if window is None or window <= 0 or d <= window:
-            return _phase_nd(selected, f)
+            return coordinate_phase(selected, f)
         flat = selected.reshape(theta, d)
-        chunks = [_phase_nd(flat[:, s:s + window], f)
+        chunks = [coordinate_phase(flat[:, s:s + window], f)
                   for s in range(0, d, window)]
         return jnp.concatenate(chunks, axis=0).reshape(trailing)
 

@@ -249,7 +249,9 @@ def _combine_tile(x: jnp.ndarray, w: Optional[jnp.ndarray], n: int, f: int,
 
     Coordinate modes sort the worker rows directly; distance modes first
     contract with the selection weights — an exact row gather when the
-    weights are one-hot f32 — then run the mode's reduction."""
+    weights are one-hot f32 and the product is full f32 (the MXU's
+    default precision rounds ``x`` to bf16 on a TPU) — then run the
+    mode's reduction."""
     if mode in COORD_MODES:
         rows = oe_sort_rows([x[i] for i in range(n)])
         out = (coord_median(rows) if mode == "cwmed"
@@ -257,6 +259,7 @@ def _combine_tile(x: jnp.ndarray, w: Optional[jnp.ndarray], n: int, f: int,
         return out[None, :]
     y = jax.lax.dot_general(
         w, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)           # (theta_w, block_d)
     if mode.startswith("bulyan"):
         rows = oe_sort_rows([y[t] for t in range(y.shape[0])])

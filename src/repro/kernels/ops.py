@@ -8,18 +8,18 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.bulyan_select import bulyan_select as _bulyan_select
+from repro.kernels.common import resolve_interpret
 from repro.kernels.pairwise_gram import pairwise_gram as _pairwise_gram
 
 __all__ = ["bulyan_coordinate", "pairwise_distances"]
 
-# Pallas interpret mode is pure-Python per grid step — correct everywhere,
-# fast only on TPU.  Default to the oracle on CPU, the kernel on TPU.
-_ON_TPU = jax.default_backend() == "tpu"
+# ``use_pallas=None`` takes the kernel where it runs compiled and the
+# oracle where it would run in the pure-Python Pallas interpreter, asked
+# when called: asking at import would initialise a backend.
 
 
 def pairwise_distances(grads: jnp.ndarray, *,
@@ -37,7 +37,7 @@ def pairwise_distances(grads: jnp.ndarray, *,
       ``(n, n)`` float32 squared distances, zero diagonal.
     """
     if use_pallas is None:
-        use_pallas = _ON_TPU
+        use_pallas = not resolve_interpret(None)
     if use_pallas:
         return _pairwise_gram(grads, block_d=block_d)
     return ref.pairwise_gram_ref(grads)
@@ -59,7 +59,7 @@ def bulyan_coordinate(selected: jnp.ndarray, f: int, *,
       ``(d,)`` float32 coordinate-phase aggregate.
     """
     if use_pallas is None:
-        use_pallas = _ON_TPU
+        use_pallas = not resolve_interpret(None)
     if use_pallas:
         return _bulyan_select(selected, f, block_d=block_d)
     from repro.core.bulyan import coordinate_phase

@@ -1,22 +1,25 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x input shape) on the
 production meshes, and extract the roofline terms from the compiled
 artifact.
 
 MUST be run as its own process (`python -m repro.launch.dryrun ...`): the
-XLA_FLAGS override above executes before any other import so that jax
-initializes with 512 host placeholder devices.  Smoke tests and benchmarks
-never import this module.
+XLA_FLAGS and JAX_PLATFORMS overrides above execute before any other
+import so that jax initializes with 512 host placeholder devices and
+never claims an attached accelerator.  Smoke tests and benchmarks never
+import this module.
 
 Outputs a JSON artifact per run with:
   memory_analysis   bytes per device (argument/output/temp/generated code)
   cost_analysis     HLO flops / bytes accessed
   collectives       per-op-kind byte totals parsed from the compiled HLO
   roofline          the three terms (compute/memory/collective, seconds)
-                    against v5e constants, the dominant term, and the
-                    MODEL_FLOPS / HLO_FLOPS utilization ratio
+                    against the peaks of the production chip, the
+                    dominant term, and the MODEL_FLOPS / HLO_FLOPS
+                    utilization ratio
 """
 import argparse
 import json
@@ -24,10 +27,9 @@ import re
 import time
 from typing import Any, Dict, Optional
 
-# v5e per-chip constants (assignment-specified)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
+from repro.launch.device import PRODUCTION_KIND, peaks
+
+TARGET = peaks(PRODUCTION_KIND)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -193,7 +195,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     n_chips = mesh.devices.size
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params, param_sh = S.param_specs(cfg, mesh)
         inputs = S.input_specs(cfg, shape_name, mesh)
 
@@ -329,9 +331,9 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     bytes_acc = cost.get("bytes accessed", 0.0)
     coll_bytes = sum(v["bytes"] for v in coll.values())
     mf = model_flops(cfg, shape)
-    compute_t = flops / PEAK_FLOPS
-    memory_t = bytes_acc / HBM_BW
-    coll_t = coll_bytes / ICI_BW
+    compute_t = flops / TARGET.flops
+    memory_t = bytes_acc / TARGET.hbm_bw
+    coll_t = coll_bytes / TARGET.ici_link_bw
     terms = {"compute_s": compute_t, "memory_s": memory_t,
              "collective_s": coll_t}
     record["roofline"] = {

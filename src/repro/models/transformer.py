@@ -117,15 +117,13 @@ def _attn_constrain(t: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """cfg.attn_shard == "batch": pin (b, s, h, hd) to batch-sharding over
     `model` so score einsums are local (no head_dim splitting).  Under the
     worker vmap (spmd_axis_name="data") the worker dim is inserted
-    automatically.  No-op when the batch doesn't divide or outside jit."""
-    if cfg.attn_shard != "batch":
+    automatically.  No-op when no mesh is in context (``jax.set_mesh``):
+    the single-device path has nothing to shard over."""
+    if cfg.attn_shard != "batch" or jax.sharding.get_abstract_mesh().empty:
         return t
     from jax.sharding import PartitionSpec as P
-    try:
-        return jax.lax.with_sharding_constraint(
-            t, P("model", *([None] * (t.ndim - 1))))
-    except Exception:
-        return t
+    return jax.lax.with_sharding_constraint(
+        t, P("model", *([None] * (t.ndim - 1))))
 
 
 def _self_attention(p, x, cfg: ModelConfig, slot: str, positions,

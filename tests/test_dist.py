@@ -94,6 +94,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_reduced
+    from repro.dist.mesh import make_host_mesh
     from repro.dist.sharding import param_shardings, batch_pspec
     from repro.dist.train import DistByzantineSpec, make_train_step
     from repro.models import init_model
@@ -101,7 +102,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
 
     assert jax.device_count() == 8
     cfg = get_reduced("llama3_2_3b")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh((4, 2))
     key = jax.random.PRNGKey(0)
     params = init_model(key, cfg)
     opt = get_optimizer("momentum", 1e-2)
@@ -118,7 +119,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     ref_params, ref_state, ref_m = jax.jit(step)(params, opt.init(params),
                                                  batch)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         psh = param_shardings(params, mesh)
         sp = jax.device_put(params, psh)
         so = jax.device_put(opt.init(params), param_shardings(

@@ -4,6 +4,7 @@ The full-size matrix is produced by repro.launch.sweep (see EXPERIMENTS.md).
 """
 import json
 import os
+import pathlib
 import subprocess
 import sys
 import tempfile
@@ -96,3 +97,33 @@ def test_long_500k_skip_rules():
     assert not shape_applicable("whisper-medium", "long_500k")
     assert not shape_applicable("llama-3.2-vision-11b", "long_500k")
     assert shape_applicable("gemma-2b", "train_4k")
+
+
+def test_peaks_by_device_kind():
+    """The roofline peaks come from one table keyed by ``device_kind``; a
+    chip that is not in it raises instead of borrowing v5e numbers."""
+    from repro.launch.device import PRODUCTION_KIND, peaks
+    assert peaks(PRODUCTION_KIND).hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no peak rates"):
+        peaks("TPU v4")
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins untouched; without it the cache
+    goes to the checkout's one fixed directory."""
+    import jax
+    from repro.launch import device
+    set_dirs = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: set_dirs.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert device.enable_compile_cache() == str(device.CACHE_DIR)
+        assert set_dirs == [("jax_compilation_cache_dir",
+                             str(device.CACHE_DIR))]
+        assert device.CACHE_DIR == pathlib.Path(REPO) / ".jax_cache"
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert device.enable_compile_cache() == env_dir
+        assert set_dirs == []

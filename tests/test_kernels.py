@@ -1,5 +1,9 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp
 oracles, in interpret mode (CPU container; kernels target TPU)."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +13,8 @@ from repro.kernels import bulyan_select, coord_stats, pairwise_gram, ref
 from repro.kernels.ops import bulyan_coordinate, pairwise_distances
 
 KEY = jax.random.PRNGKey(7)
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                   "src")
 
 
 @pytest.mark.parametrize("n,d", [(5, 64), (7, 100), (9, 129), (16, 2048),
@@ -78,6 +84,19 @@ def test_ops_wrappers_dispatch():
     np.testing.assert_allclose(
         bulyan_coordinate(s, 2, use_pallas=True, block_d=128),
         bulyan_coordinate(s, 2, use_pallas=False), rtol=1e-5, atol=1e-5)
+
+
+def test_import_initialises_no_backend():
+    """Importing the kernels (and the runtime above them) must not ask for
+    the backend: on a chip that would claim the device as an import side
+    effect.  The wrappers resolve it when called."""
+    code = ("import repro.kernels, repro.dist, repro.serving\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ, PYTHONPATH=SRC)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-3000:]
 
 
 def test_gram_padding_exact():
