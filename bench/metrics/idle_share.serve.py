@@ -1,0 +1,7 @@
+"""Share of the traced window of the serving loop in which no operation
+ran on the device, in percent, averaged over the cell's chips."""
+
+
+def read(ctx):
+    share = ctx["trace"].get("idle_share")
+    return None if share is None else 100.0 * share
