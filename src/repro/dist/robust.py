@@ -62,7 +62,6 @@ distance-based GAR, and it has two interchangeable implementations behind
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -242,25 +241,23 @@ def pairwise_sq_dists_tree(tree: Any, compute_dtype=jnp.float32, *,
     # the "fused" knob reroutes the *rule* (see distributed_aggregate);
     # its distance matrix, when a rule still asks for one, is the same
     # tiled Pallas accumulation
-    with named_span("agg/gram"):
-        if backend in ("pallas", "fused"):
-            from repro.dist.mesh import mesh_axis_sizes
-            if mesh is not None and mesh_axis_sizes(mesh).get("model",
-                                                              1) > 1:
-                d2 = _pallas_sharded_dists(tree, mesh, block_d=block_d,
-                                           interpret=interpret)
-            else:
-                d2 = pairwise_gram_tree(tree, block_d=block_d,
-                                        interpret=interpret)
-            return d2.astype(compute_dtype)
-        gram = jnp.zeros((n, n), compute_dtype)
-        sq = jnp.zeros((n,), compute_dtype)
-        for leaf in _leaves(tree):
-            x = leaf.astype(compute_dtype)
-            axes = _trailing_axes(leaf)
-            gram = gram + jnp.tensordot(x, x, axes=(axes, axes))
-            sq = sq + jnp.sum(x * x, axis=axes)
-        return finalize_dists(sq[:, None] + sq[None, :] - 2.0 * gram)
+    if backend in ("pallas", "fused"):
+        from repro.dist.mesh import mesh_axis_sizes
+        if mesh is not None and mesh_axis_sizes(mesh).get("model", 1) > 1:
+            d2 = _pallas_sharded_dists(tree, mesh, block_d=block_d,
+                                       interpret=interpret)
+        else:
+            d2 = pairwise_gram_tree(tree, block_d=block_d,
+                                    interpret=interpret)
+        return d2.astype(compute_dtype)
+    gram = jnp.zeros((n, n), compute_dtype)
+    sq = jnp.zeros((n,), compute_dtype)
+    for leaf in _leaves(tree):
+        x = leaf.astype(compute_dtype)
+        axes = _trailing_axes(leaf)
+        gram = gram + jnp.tensordot(x, x, axes=(axes, axes))
+        sq = sq + jnp.sum(x * x, axis=axes)
+    return finalize_dists(sq[:, None] + sq[None, :] - 2.0 * gram)
 
 
 # ---------------------------------------------------------------------------
@@ -290,13 +287,12 @@ def coordinate_phase_nd(selected: jnp.ndarray, f: int,
             f"beta = theta - 2f must be >= 1 (theta={theta}, f={f})")
     trailing = selected.shape[1:]
     d = math.prod(trailing)
-    with named_span("agg/coordinate"):
-        if window is None or window <= 0 or d <= window:
-            return coordinate_phase(selected, f)
-        flat = selected.reshape(theta, d)
-        chunks = [coordinate_phase(flat[:, s:s + window], f)
-                  for s in range(0, d, window)]
-        return jnp.concatenate(chunks, axis=0).reshape(trailing)
+    if window is None or window <= 0 or d <= window:
+        return coordinate_phase(selected, f)
+    flat = selected.reshape(theta, d)
+    chunks = [coordinate_phase(flat[:, s:s + window], f)
+              for s in range(0, d, window)]
+    return jnp.concatenate(chunks, axis=0).reshape(trailing)
 
 
 # ---------------------------------------------------------------------------
@@ -376,25 +372,34 @@ def distributed_aggregate(tree: Any, f: int, gar: str = "bulyan-krum", *,
 
     def make_dists(ls):
         t = jax.tree_util.tree_unflatten(treedef, list(ls))
-        return pairwise_sq_dists_tree(t, cdt,
-                                      distance_backend=distance_backend,
-                                      mesh=mesh)
+        with named_span("gram"):
+            return pairwise_sq_dists_tree(t, cdt,
+                                          distance_backend=distance_backend,
+                                          mesh=mesh)
+
+    def coordinate(stack, f_):
+        with named_span("coordinate"):
+            return coordinate_phase_nd(stack, f_, window=window)
 
     ctx = TreeContext(
         leaves=tuple(leaves), n=n, f=f, cdt=cdt, make_dists=make_dists,
-        coordinate_phase=partial(coordinate_phase_nd, window=window))
+        coordinate_phase=coordinate)
 
-    if rule.stateful:
-        if state is None:
-            state = init_state(rule, tree, flat=False)
-        with named_span("agg/select"):
+    # every op of the rule lies under ``agg``; the engine's distances and
+    # coordinate phase, and the rules' own selection, open one phase each
+    # inside it: ``agg/gram``, ``agg/coordinate``, ``agg/select``
+    with named_span("agg"):
+        if rule.stateful:
+            if state is None:
+                state = init_state(rule, tree, flat=False)
             out, new_state = rule.tree_fn(ctx, state)
-    else:
-        with named_span("agg/select"):
+        else:
             out = rule.tree_fn(ctx)
+        with named_span("coordinate"):
+            agg_leaves = [a.astype(dt)
+                          for a, dt in zip(out.leaves, out_dtypes)]
 
-    agg_tree = jax.tree_util.tree_unflatten(
-        treedef, [a.astype(dt) for a, dt in zip(out.leaves, out_dtypes)])
+    agg_tree = jax.tree_util.tree_unflatten(treedef, agg_leaves)
     res = DistAggResult(out.selected, out.scores)
     if rule.stateful:
         return agg_tree, res, new_state

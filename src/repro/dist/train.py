@@ -31,6 +31,7 @@ from repro.dist.robust import distributed_aggregate, inject_byzantine
 from repro.models import forward
 from repro.models.config import ModelConfig
 from repro.obs.schema import core_metrics, global_norm, selection_weight
+from repro.obs.trace import named_span
 from repro.optim import Optimizer
 
 __all__ = ["DistByzantineSpec", "init_agg_state", "make_loss_fn",
@@ -129,20 +130,26 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
         f = spec.f
         n_h = n - f
 
-        if extra is None:
-            losses, grads = jax.vmap(
-                lambda t, l: vg(params, t, l))(tokens, labels)
-        else:
-            losses, grads = jax.vmap(
-                lambda t, l, e: vg(params, t, l, e))(tokens, labels, extra)
+        # each phase under one scope placed outside its transforms, so
+        # the backward pass reads ``train/grads/vmap(transpose(jvp()))``;
+        # the aggregation opens ``agg/...`` of its own
+        with named_span("train/grads"):
+            if extra is None:
+                losses, grads = jax.vmap(
+                    lambda t, l: vg(params, t, l))(tokens, labels)
+            else:
+                losses, grads = jax.vmap(
+                    lambda t, l, e: vg(params, t, l, e))(tokens, labels,
+                                                         extra)
 
         if spec.attack != "none" and f > 0:
-            key = jax.random.fold_in(jax.random.PRNGKey(spec.seed),
-                                     opt_state["step"])
-            akw = dict(spec.attack_kwargs)
-            akw.setdefault("gar_name", spec.gar)
-            grads = inject_byzantine(grads, f, spec.attack, key=key,
-                                     step=opt_state["step"], **akw)
+            with named_span("train/inject"):
+                key = jax.random.fold_in(jax.random.PRNGKey(spec.seed),
+                                         opt_state["step"])
+                akw = dict(spec.attack_kwargs)
+                akw.setdefault("gar_name", spec.gar)
+                grads = inject_byzantine(grads, f, spec.attack, key=key,
+                                         step=opt_state["step"], **akw)
 
         out = distributed_aggregate(
             grads, spec.f_declared, spec.effective_gar,
@@ -153,47 +160,49 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
         agg, res = out[0], out[1]
         new_agg_state = out[2] if stateful else None
 
-        step_scale = jnp.ones((), jnp.float32)
-        if reputed:
-            from repro.agg.reputation import (
-                DEFAULT_REP_DECAY, DEFAULT_REP_LR, step_size_multiplier,
-                tree_reputation_scores, update_reputation)
-            if spec.aux_batch is not None:
-                # ByGARS proper: score raw submissions against the clean
-                # auxiliary gradient, overriding the rule's own
-                # agreement-with-the-aggregate update — the only signal
-                # a colluding majority cannot vote on
-                aux = tuple(spec.aux_batch)
-                _, clean = vg(params, *aux)
-                scores = tree_reputation_scores(
-                    jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(clean))
-                lr = (DEFAULT_REP_LR if spec.rep_lr is None
-                      else spec.rep_lr)
-                decay = (DEFAULT_REP_DECAY if spec.rep_decay is None
-                         else spec.rep_decay)
-                new_agg_state = new_agg_state._replace(
-                    reputation=update_reputation(
-                        agg_state.reputation, scores, lr, decay))
-            if spec.rep_lr:
-                # staleness-adaptive step size (Alistarh et al.): the
-                # same carried trust scales the update magnitude
-                step_scale = step_size_multiplier(new_agg_state)
-                agg = jax.tree_util.tree_map(
-                    lambda a: (a.astype(jnp.float32)
-                               * step_scale).astype(a.dtype), agg)
-        new_params, new_state = optimizer.update(agg, opt_state, params)
+        with named_span("train/optimizer"):
+            step_scale = jnp.ones((), jnp.float32)
+            if reputed:
+                from repro.agg.reputation import (
+                    DEFAULT_REP_DECAY, DEFAULT_REP_LR, step_size_multiplier,
+                    tree_reputation_scores, update_reputation)
+                if spec.aux_batch is not None:
+                    # ByGARS proper: score raw submissions against the clean
+                    # auxiliary gradient, overriding the rule's own
+                    # agreement-with-the-aggregate update — the only signal
+                    # a colluding majority cannot vote on
+                    aux = tuple(spec.aux_batch)
+                    _, clean = vg(params, *aux)
+                    scores = tree_reputation_scores(
+                        jax.tree_util.tree_leaves(grads),
+                        jax.tree_util.tree_leaves(clean))
+                    lr = (DEFAULT_REP_LR if spec.rep_lr is None
+                          else spec.rep_lr)
+                    decay = (DEFAULT_REP_DECAY if spec.rep_decay is None
+                             else spec.rep_decay)
+                    new_agg_state = new_agg_state._replace(
+                        reputation=update_reputation(
+                            agg_state.reputation, scores, lr, decay))
+                if spec.rep_lr:
+                    # staleness-adaptive step size (Alistarh et al.): the
+                    # same carried trust scales the update magnitude
+                    step_scale = step_size_multiplier(new_agg_state)
+                    agg = jax.tree_util.tree_map(
+                        lambda a: (a.astype(jnp.float32)
+                                   * step_scale).astype(a.dtype), agg)
+            new_params, new_state = optimizer.update(agg, opt_state, params)
 
-        honest_mean = jax.tree_util.tree_map(
-            lambda g: jnp.mean(g[:n_h].astype(jnp.float32), axis=0), grads)
-        dev = jax.tree_util.tree_map(
-            lambda a, m: a.astype(jnp.float32) - m, agg, honest_mean)
-        metrics = core_metrics(
-            loss=jnp.mean(losses[:n_h]),
-            grad_norm=global_norm(agg),
-            agg_dev=global_norm(dev),
-            byz_weight=selection_weight(res.selected, n_h),
-            step_scale=step_scale if reputed else None)
+        with named_span("train/diagnostics"):
+            honest_mean = jax.tree_util.tree_map(
+                lambda g: jnp.mean(g[:n_h].astype(jnp.float32), axis=0), grads)
+            dev = jax.tree_util.tree_map(
+                lambda a, m: a.astype(jnp.float32) - m, agg, honest_mean)
+            metrics = core_metrics(
+                loss=jnp.mean(losses[:n_h]),
+                grad_norm=global_norm(agg),
+                agg_dev=global_norm(dev),
+                byz_weight=selection_weight(res.selected, n_h),
+                step_scale=step_scale if reputed else None)
         return new_params, new_state, metrics, new_agg_state
 
     if stateful:

@@ -12,8 +12,9 @@ docs/observability.md).  Four pieces, all importable from here:
 * ``repro.obs.detect`` — host-side attack detectors (selection-entropy
   collapse, suspicion ranking, ε-margin trajectory);
 * ``repro.obs.schema`` / ``repro.obs.trace`` / ``repro.obs.export`` —
-  the shared train-metrics schema, named-scope + span-timer tracing
-  hooks, and JSONL/CSV writers.
+  the shared train-metrics schema, the tracing hooks (in-graph named
+  scopes, host spans on the profiler's clock, compile counts), and
+  JSONL/CSV writers.
 
 Enable end to end with ``AggSpec(..., telemetry=True)`` — every train /
 async / serve step then aggregates through ``spec.effective_gar``
@@ -31,21 +32,20 @@ from repro.obs.forensics import (dense_diagnostics, make_obs, obs_name,
                                  tree_diagnostics)
 from repro.obs.schema import (METRIC_SCHEMA, async_extras, core_metrics,
                               global_norm, selection_weight)
-from repro.obs.trace import (EVENT_FIELDS, SpanTimer, named_span,
-                             span_event)
+from repro.obs.trace import count_compiles, host_span, named_span
 
 __all__ = [
     "AggDiagnostics",
     "DEFAULT_OBS_CAPACITY",
-    "EVENT_FIELDS",
     "METRIC_SCHEMA",
     "MetricsBuffer",
-    "SpanTimer",
     "async_extras",
     "core_metrics",
+    "count_compiles",
     "dense_diagnostics",
     "drain",
     "global_norm",
+    "host_span",
     "init_metrics_buffer",
     "make_obs",
     "margin_trajectory",
@@ -56,7 +56,6 @@ __all__ = [
     "selection_collapsed",
     "selection_entropy",
     "selection_weight",
-    "span_event",
     "suspicion_scores",
     "to_jsonable",
     "tree_diagnostics",

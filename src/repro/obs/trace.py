@@ -1,31 +1,40 @@
-"""Tracing hooks: in-graph named scopes + a host-side span timer.
+"""Tracing hooks: in-graph named scopes, host spans and compile counts.
 
-Two complementary layers share one event schema (:data:`EVENT_FIELDS`):
+Everything lands on the profiler's own clock (``jax.profiler.trace``), so
+one trace lines up the device's ops with the host code that issued them:
 
 * :func:`named_span` — a zero-cost ``jax.named_scope`` wrapper the hot
-  paths wear around their phases (``agg/gram``, ``agg/select``,
-  ``agg/coordinate``, ``serve/verify``, ``kernel/fused``), so profiler
-  timelines (``jax.profiler.trace``) and HLO dumps carry readable phase
-  names.  Metadata-only: it never changes the computation.
-* :class:`SpanTimer` — a host-side wall-clock timer whose
-  ``with timer.span("name")`` blocks become event rows; benchmarks and
-  the serving engine export them as JSONL with the same schema the
-  roofline/p99 rows use, so one tooling path reads both.
+  paths wear around their phases (``train/grads``, ``agg/gram``,
+  ``agg/select``, ``agg/coordinate``, ``serve/verify``, ``kernel/fused``),
+  so device ops and HLO op names carry readable phase names.
+  Metadata-only: it never changes the computation.
+* :func:`host_span` — a ``jax.profiler.TraceAnnotation`` around host code
+  (``serve/admit``, ``serve/step/sample``, ...), recorded in the same
+  trace as the device ops; it costs under a microsecond when no profiler
+  is running.
+* :func:`count_compiles` — adds the JAX compile work done inside a block
+  to a dict of plain counters (``compiles``, ``compile_s``).
+
+Span names are ``layer/phase``; a host span's keyword metadata (such as
+``rid``) is kept as statistics of its trace event.
 """
 from __future__ import annotations
 
 import contextlib
-import json
-import time
-from typing import Any, Dict, List
+from typing import Dict, Iterator
 
 import jax
 
-__all__ = ["EVENT_FIELDS", "SpanTimer", "named_span", "span_event"]
+__all__ = ["count_compiles", "host_span", "named_span"]
 
-#: the shared event schema: every exported timing row carries exactly
-#: these keys (``meta`` is a free-form dict — backend, shape, seed, ...)
-EVENT_FIELDS = ("name", "us", "meta")
+#: the ``jax.monitoring`` events :func:`count_compiles` listens to: tracing
+#: to a jaxpr, lowering to MLIR, and the backend compile (which holds the
+#: persistent compilation cache's retrieval when the executable is found
+#: there, ``/jax/compilation_cache/cache_retrieval_time_sec``)
+_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+_BACKEND_COMPILE = _COMPILE_EVENTS[2]
 
 
 def named_span(name: str):
@@ -45,67 +54,66 @@ def named_span(name: str):
     return jax.named_scope(name)
 
 
-def span_event(name: str, us: float, **meta: Any) -> Dict[str, Any]:
-    """One timing event row in the shared schema.
+def host_span(name: str, **meta) -> jax.profiler.TraceAnnotation:
+    """A span of host code on the profiler's timeline.
 
     Args:
-      name: event label (phase or benchmark row name).
-      us: duration in microseconds.
-      **meta: free-form metadata (backend, n, d, seed, ...).
+      name: ``layer/phase`` label; a child span extends its parent's
+        label (``serve/admit`` holds ``serve/admit/prefill``).
+      **meta: statistics recorded with the span (``rid=`` for the spans
+        of one request).
 
     Returns:
-      Dict with exactly :data:`EVENT_FIELDS`.
+      A ``jax.profiler.TraceAnnotation`` context manager: recorded when a
+      profiler is running, a no-op otherwise.
     """
-    return {"name": name, "us": float(us), "meta": dict(meta)}
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
-class SpanTimer:
-    """Host-side wall-clock span collector with JSONL export.
+def _union_s(spans) -> float:
+    """Seconds covered by the union of ``(start, end)`` spans."""
+    total, end = 0.0, None
+    for s, e in sorted(spans):
+        if end is None or s > end:
+            total += e - s
+            end = e
+        elif e > end:
+            total += e - end
+            end = e
+    return total
 
-    Usage::
 
-        timer = SpanTimer()
-        with timer.span("serve/decode_step", batch=8):
-            engine.step()
-        timer.export_jsonl("events.jsonl")
+@contextlib.contextmanager
+def count_compiles(counters: Dict[str, float]) -> Iterator[Dict[str, float]]:
+    """Add the compile work done inside the block to ``counters``.
 
-    Spans time host-observed wall clock (``time.perf_counter``) — call
-    ``jax.block_until_ready`` inside the block when device work must be
-    included.  The collected rows follow :data:`EVENT_FIELDS`.
+    ``counters["compiles"]`` grows by the executables built or loaded
+    from the persistent compilation cache (one backend compile event
+    each), ``counters["compile_s"]`` by the seconds spent tracing,
+    lowering and compiling.  Nested events (a jaxpr traced while another
+    is traced, a cache retrieval inside its backend compile) are counted
+    once: the seconds are the union of the events' time spans.  The
+    listener is removed when the block exits, also on an exception.  Do
+    not nest two blocks over one dict: each would count the same work.
+
+    Args:
+      counters: dict holding ``"compiles"`` and ``"compile_s"``, updated
+        in place.
+
+    Returns:
+      A context manager yielding ``counters``.
     """
+    spans = []
 
-    def __init__(self) -> None:
-        self.events: List[Dict[str, Any]] = []
+    def on_span(event: str, start: float, end: float, **_kw) -> None:
+        if event in _COMPILE_EVENTS:
+            spans.append((start, end))
+            if event == _BACKEND_COMPILE:
+                counters["compiles"] += 1
 
-    @contextlib.contextmanager
-    def span(self, name: str, **meta: Any):
-        """Time one ``with`` block as an event row.
-
-        Args:
-          name: event label.
-          **meta: free-form metadata attached to the row.
-
-        Returns:
-          A context manager appending one :func:`span_event` row on
-          exit (also on exception, so partial runs keep their timeline).
-        """
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            us = (time.perf_counter() - t0) * 1e6
-            self.events.append(span_event(name, us, **meta))
-
-    def export_jsonl(self, path) -> int:
-        """Write the collected events as one JSON object per line.
-
-        Args:
-          path: destination file path (overwritten).
-
-        Returns:
-          Number of event rows written.
-        """
-        with open(path, "w") as fh:
-            for ev in self.events:
-                fh.write(json.dumps(ev) + "\n")
-        return len(self.events)
+    jax.monitoring.register_event_time_span_listener(on_span)
+    try:
+        yield counters
+    finally:
+        jax.monitoring.unregister_event_time_span_listener(on_span)
+        counters["compile_s"] += _union_s(spans)

@@ -36,6 +36,7 @@ all and reproduces the per-token stream bitwise.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional
 
 import jax
@@ -44,6 +45,7 @@ import numpy as np
 
 from repro.models import decode_step, init_cache, prefill
 from repro.models.config import ModelConfig
+from repro.obs.trace import count_compiles, host_span
 
 __all__ = ["Request", "ServingEngine"]
 
@@ -53,7 +55,9 @@ class Request:
     """One serving request: a prompt plus generation bookkeeping.
 
     ``generated`` accumulates sampled token ids (filled by the engine);
-    ``done`` flips when ``max_new_tokens`` have been produced.
+    ``done`` flips when ``max_new_tokens`` have been produced;
+    ``submitted_at`` is the ``time.perf_counter()`` stamp of
+    :meth:`ServingEngine.submit` (``None`` when admitted directly).
     """
 
     rid: int
@@ -61,6 +65,7 @@ class Request:
     max_new_tokens: int
     generated: Optional[List[int]] = None
     done: bool = False
+    submitted_at: Optional[float] = None
 
 
 class ServingEngine:
@@ -77,6 +82,16 @@ class ServingEngine:
     Host-side counters (``positions``, ``last_token``) are int32 — the
     dtype the jit'd steps consume — so no implicit int64 promotion
     happens at the host/device boundary.
+
+    ``counters`` holds the engine's running totals as plain numbers:
+    ``admissions``, ``decode_steps``, ``queue_s`` (each admitted
+    request's wait between :meth:`submit` and :meth:`admit`), and
+    ``compiles`` / ``compile_s`` (the JAX compile work done inside
+    :meth:`admit` and :meth:`step`, see
+    :func:`repro.obs.trace.count_compiles`).  Admission and decoding run
+    under host spans (``serve/admit``, ``serve/step`` and their phases,
+    :func:`repro.obs.trace.host_span`) that a profiler trace shows
+    beside the device ops.
     """
 
     def __init__(self, params, cfg: ModelConfig, n_slots: int = 4,
@@ -94,6 +109,9 @@ class ServingEngine:
         self.agg_state = None
         self.spec_k = 0
         self.accept_counts: List[np.ndarray] = []
+        self.counters: Dict[str, float] = {
+            "admissions": 0, "decode_steps": 0, "queue_s": 0.0,
+            "compiles": 0, "compile_s": 0.0}
         if ensemble is None:
             self.params = params
             self.cache = init_cache(cfg, n_slots, cache_len)
@@ -183,32 +201,48 @@ class ServingEngine:
         slot = self._free_slot()
         if slot is None:
             return False
+        with host_span("serve/admit", rid=req.rid), \
+                count_compiles(self.counters):
+            self._admit_into(slot, req)
+        return True
+
+    def _admit_into(self, slot: int, req: Request) -> None:
+        """Prefill ``req``, take its first token and splice its cache into
+        ``slot``, one host span per phase."""
+        if req.submitted_at is not None:
+            self.counters["queue_s"] += time.perf_counter() - req.submitted_at
+        self.counters["admissions"] += 1
         req.generated = []
         tokens = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        if self.ensemble is None:
-            logits, slot_cache = prefill(self.params, self.cfg, tokens,
-                                         cache_len=self.cache_len)
-            first = int(jnp.argmax(logits[0, -1]))
-        else:
-            agg_logits, slot_cache, _ = self._ens_prefill(self.params, tokens)
-            first = int(jnp.argmax(agg_logits[0]))
-        self._splice_cache(slot, slot_cache)
+        with host_span("serve/admit/prefill", rid=req.rid):
+            if self.ensemble is None:
+                logits, slot_cache = prefill(self.params, self.cfg, tokens,
+                                             cache_len=self.cache_len)
+                last = logits[0, -1]
+            else:
+                agg_logits, slot_cache, _ = self._ens_prefill(self.params,
+                                                              tokens)
+                last = agg_logits[0]
+        with host_span("serve/admit/first_token", rid=req.rid):
+            first = int(jnp.argmax(last))
+        with host_span("serve/admit/splice", rid=req.rid):
+            self._splice_cache(slot, slot_cache)
+            if self.spec_k:
+                from repro.serving.speculative import draft_cache_view
+                self.draft_cache = self._spliced(
+                    self.draft_cache, slot,
+                    draft_cache_view(slot_cache, self.draft_replica),
+                    replicated=False)
         if self.ensemble is not None:
             # a reused slot must not inherit the previous occupant's
             # sliding-window / momentum aggregation history
             from repro.dist.serve_robust import reset_slot_state
-            self.agg_state = reset_slot_state(self.agg_state, slot)
-        if self.spec_k:
-            from repro.serving.speculative import draft_cache_view
-            self.draft_cache = self._spliced(
-                self.draft_cache, slot,
-                draft_cache_view(slot_cache, self.draft_replica),
-                replicated=False)
+            with host_span("serve/admit/reset", rid=req.rid):
+                self.agg_state = reset_slot_state(self.agg_state, slot)
         self.active[slot] = req
         self.positions[slot] = len(req.prompt)
         self.last_token[slot] = first
         req.generated.append(first)
-        return True
 
     def submit(self, req: Request) -> None:
         """Queue a request for admission at the next :meth:`step`.
@@ -217,6 +251,7 @@ class ServingEngine:
         mid-stream (continuous batching) without the caller tracking slot
         occupancy.
         """
+        req.submitted_at = time.perf_counter()
         self.pending.append(req)
 
     def _admit_pending(self) -> None:
@@ -238,29 +273,40 @@ class ServingEngine:
         self._admit_pending()
         if not any(r is not None for r in self.active):
             return
-        if self.spec_k:
-            self._step_speculative()
-            return
-        tokens = jnp.asarray(self.last_token)[:, None]
-        # per-slot positions: each sequence ropes/writes at its own index
-        pos = jnp.asarray(self.positions, jnp.int32)
-        if self.ensemble is None:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              tokens, pos)
-            step_logits = logits[:, 0]
-        else:
-            step_logits, self.cache, _res, self.agg_state = self._decode(
-                self.params, self.cache, tokens, pos, self.agg_state)
-        nxt = np.asarray(jnp.argmax(step_logits, axis=-1), np.int32)
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.last_token[i] = nxt[i]
-            req.generated.append(int(nxt[i]))
-            self.positions[i] += 1
-            if len(req.generated) >= req.max_new_tokens:
-                req.done = True
-                self.active[i] = None
+        self.counters["decode_steps"] += 1
+        with host_span("serve/step"), count_compiles(self.counters):
+            if self.spec_k:
+                self._step_speculative()
+            else:
+                self._step_per_token()
+
+    def _step_per_token(self) -> None:
+        """One token for every active slot: decode, sample, emit."""
+        with host_span("serve/step/decode"):
+            tokens = jnp.asarray(self.last_token)[:, None]
+            # per-slot positions: each sequence ropes/writes at its own
+            # index
+            pos = jnp.asarray(self.positions, jnp.int32)
+            if self.ensemble is None:
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  tokens, pos)
+                step_logits = logits[:, 0]
+            else:
+                step_logits, self.cache, _res, self.agg_state = self._decode(
+                    self.params, self.cache, tokens, pos, self.agg_state)
+        with host_span("serve/step/sample"):
+            # the host waits here for the device's step
+            nxt = np.asarray(jnp.argmax(step_logits, axis=-1), np.int32)
+        with host_span("serve/step/emit"):
+            for i, req in enumerate(self.active):
+                if req is None:
+                    continue
+                self.last_token[i] = nxt[i]
+                req.generated.append(int(nxt[i]))
+                self.positions[i] += 1
+                if len(req.generated) >= req.max_new_tokens:
+                    req.done = True
+                    self.active[i] = None
 
     def _step_speculative(self) -> None:
         """One speculative engine step: draft k-1, verify k, emit 1..k.
@@ -301,7 +347,8 @@ class ServingEngine:
         not set ``telemetry=True`` — extended with the speculative
         acceptance record: ``accept_counts`` is the ``(steps, n_slots)``
         per-step accepted-prefix-length history and ``accept_mean`` its
-        scalar mean (0.0 before any speculative step ran).
+        scalar mean (0.0 before any speculative step ran), and with
+        ``counters``, a copy of :attr:`counters`.
         """
         from repro.obs.buffer import drain
         obs = self.agg_state.obs if self.agg_state is not None else ()
@@ -311,6 +358,7 @@ class ServingEngine:
                                                       np.int32))
         report["accept_counts"] = counts
         report["accept_mean"] = float(counts.mean()) if counts.size else 0.0
+        report["counters"] = dict(self.counters)
         return report
 
     def run(self, requests: List[Request], max_steps: int = 1000
