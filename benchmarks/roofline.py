@@ -12,8 +12,9 @@ Two row families:
   backend, from *itemized HBM-byte models* (every term printed in the
   derived column, so the claimed step-times are auditable):
 
-    xla     tensordot distances + gathered (theta, d) sort / cumsum /
-            window phase — every intermediate round-trips HBM;
+    xla     tensordot distances + the coordinate phase as one fused
+            sweep over the theta selected rows (sorting network and
+            prefix-sum window; nothing round-trips HBM);
     pallas  kernel pair: tiled Gram + fused coordinate kernel; the
             (theta, d) gather still materializes between them;
     fused   the megakernel (``repro.kernels.fused_agg``): two input
@@ -73,16 +74,9 @@ def _agg_bytes(backend: str, n: int, f: int, d: int) -> Dict[str, float]:
             "write_agg": d * F32,
         }
     if backend == "xla":
-        beta = theta - 2 * f
-        n_win = theta - beta + 1
         return {
             "dist_read_grads": n * d * BF16,
-            "gather_read_theta": theta * d * BF16,
-            "gather_write_f32": theta * d * F32,
-            "sort_read+write": 2 * theta * d * F32,
-            "cumsum_dev_read+write": 2 * (theta + 1) * d * F32,
-            "cumsum_val_read+write": 2 * (theta + 1) * d * F32,
-            "window_read_prefix": 2 * n_win * d * F32,
+            "coord_read_theta": theta * d * BF16,
             "write_agg": d * F32,
         }
     raise KeyError(f"unknown backend {backend!r}")

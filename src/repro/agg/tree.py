@@ -118,7 +118,8 @@ def bulyan_tree(ctx: TreeContext, base: str = "krum") -> TreeAgg:
 
     Phase 1 runs on the (n, n) distance matrix alone
     (``select_indices_from_dists``); phase 2 is the engine's windowed
-    coordinate phase, applied per leaf so each leaf keeps its sharding.
+    coordinate phase, applied per leaf so each leaf keeps its sharding,
+    to the theta selected rows taken as dynamic slices of the leaf.
 
     Args:
       ctx: the engine-prepared tree context.
@@ -132,10 +133,16 @@ def bulyan_tree(ctx: TreeContext, base: str = "krum") -> TreeAgg:
     d2 = ctx.dists()
     with named_span("select"):
         idx = bulyan_lib.select_indices_from_dists(d2, ctx.f, base=base)
+    theta = idx.shape[0]
     agg = []
     for leaf in ctx.leaves:
+        # theta dynamic row slices, not a gather: XLA fuses them into the
+        # coordinate phase's sweep instead of materializing the rows
         with named_span("coordinate"):
-            rows = jnp.take(leaf.astype(ctx.cdt), idx, axis=0)
+            rows = jnp.stack([
+                jax.lax.dynamic_index_in_dim(leaf, idx[t], 0,
+                                             keepdims=False).astype(ctx.cdt)
+                for t in range(theta)])
         agg.append(ctx.coordinate_phase(rows, ctx.f))
     with named_span("select"):
         selected = jnp.zeros((ctx.n,), ctx.cdt).at[idx].set(1.0)

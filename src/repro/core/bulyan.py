@@ -15,9 +15,14 @@ Two phases:
    absolute deviations — a 1-D medoid).
 
 The coordinate phase is exposed standalone (``coordinate_phase``) because it
-is what the Pallas kernel (``repro.kernels.bulyan_select``) and the
-model-axis-sharded distributed implementation (``repro.dist.robust``) reuse:
-it is embarrassingly parallel over coordinates.
+is what the Pallas kernels (``repro.kernels.bulyan_select``,
+``repro.kernels.fused_agg``) and the model-axis-sharded distributed
+implementation (``repro.dist.robust``) run: it is embarrassingly parallel
+over coordinates.  It is one elementwise sweep over the theta rows — an
+unrolled sorting network (``oe_sort_rows``) and a prefix-sum window
+(``bulyan_window``) — with no sort, cumulative sum or gather along the
+worker axis, so XLA fuses it (and the row slices feeding it) into a single
+pass, and the kernels run the very same body on their VMEM tiles.
 
 Note on the recursion depth: with theta = n - 2f iterations the last call to
 A sees 2f + 1 vectors.  Krum's neighbour count n' - f - 2 can then reach 0
@@ -27,7 +32,7 @@ implementation's behaviour (LPD-EPFL/bulyan).
 from __future__ import annotations
 
 import itertools
-from typing import Callable, Optional
+from typing import Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -124,41 +129,106 @@ def select_indices(grads: jnp.ndarray, f: int, base: str = "krum",
     return jnp.stack(picked)
 
 
+def oe_sort_rows(rows: List[jnp.ndarray]) -> List[jnp.ndarray]:
+    """Odd-even transposition sort across a list of rows (axis 0).
+
+    Fully unrolled for the static row count (worker counts are <= a few
+    dozen): no data-dependent control flow, exactly ``m * (m - 1) / 2``
+    min/max pairs on the row vectors — elementwise work XLA fuses into
+    one pass, and the TPU-safe substitute for ``jnp.sort(axis=0)``
+    inside a kernel body.
+
+    Args:
+      rows: list of equally-shaped arrays, one per row of the stack
+        being sorted (``(block_d,)`` lane vectors inside a kernel).
+
+    Returns:
+      New list with the rows sorted ascending per element (the inputs
+      are not mutated).
+    """
+    m = len(rows)
+    rows = list(rows)
+    for p in range(m):
+        for i in range(p % 2, m - 1, 2):
+            a, b = rows[i], rows[i + 1]
+            rows[i] = jnp.minimum(a, b)
+            rows[i + 1] = jnp.maximum(a, b)
+    return rows
+
+
+def bulyan_window(rows: List[jnp.ndarray], f: int) -> jnp.ndarray:
+    """Bulyan's coordinate phase on an already-sorted row list.
+
+    Per element: the mean of the ``beta = theta - 2f`` sorted values
+    closest to the median (the lower-middle row).  The beta-closest set
+    is a *contiguous window* of the sorted order, so it reduces to
+    running prefix sums of the values and of ``|x - med|`` plus a
+    strict-``<`` ``where`` chain over the ``theta - beta + 1`` windows
+    (the first window wins ties) — no gather, no second sort.
+
+    Args:
+      rows: ``theta`` sorted rows (ascending per element), e.g. the
+        output of :func:`oe_sort_rows`.
+      f: Byzantine bound; requires ``beta = theta - 2f >= 1``.
+
+    Returns:
+      One row: per element, the best window mean.
+    """
+    theta = len(rows)
+    beta = theta - 2 * f
+    med = rows[(theta - 1) // 2]
+
+    if beta == theta:
+        acc = rows[0]
+        for r in rows[1:]:
+            acc = acc + r
+        return acc / beta
+
+    # prefix sums of sorted values and |sorted - med|
+    pref_v = [jnp.zeros_like(med)]
+    pref_d = [jnp.zeros_like(med)]
+    for r in rows:
+        pref_v.append(pref_v[-1] + r)
+        pref_d.append(pref_d[-1] + jnp.abs(r - med))
+
+    n_win = theta - beta + 1
+    best_dev = pref_d[beta] - pref_d[0]
+    best_sum = pref_v[beta] - pref_v[0]
+    for w in range(1, n_win):
+        dev = pref_d[w + beta] - pref_d[w]
+        s = pref_v[w + beta] - pref_v[w]
+        take = dev < best_dev                      # first-window tiebreak
+        best_dev = jnp.where(take, dev, best_dev)
+        best_sum = jnp.where(take, s, best_sum)
+    return best_sum / beta
+
+
 def coordinate_phase(selected: jnp.ndarray, f: int) -> jnp.ndarray:
     """Phase 2 on a (theta, ...) stack: per-coordinate average of the beta
     values closest to the coordinate-wise median.
 
-    Key structural fact (reused by the Pallas kernel): after sorting each
-    coordinate's theta values, the beta values closest to the median form a
-    *contiguous window* of the sorted order.  We therefore sort once and
-    scan the theta - beta + 1 candidate windows via cumulative sums — no
-    second sort / argsort.
+    One elementwise sweep: the theta rows go through the unrolled
+    sorting network (:func:`oe_sort_rows`), and the beta values closest
+    to the median — a contiguous window of the sorted order — are found
+    by :func:`bulyan_window`'s prefix sums.  No sort, cumulative sum or
+    gather runs along the worker axis, so under jit the whole phase (and
+    any row slices or casts producing ``selected``) is one fusion.  With
+    ``f = 0`` (``beta == theta``) it is the mean of the sorted values,
+    summed in order as the Pallas kernels sum them.
     """
     theta = selected.shape[0]
     beta = theta - 2 * f
     if beta < 1:
         raise ValueError(
             f"beta = theta - 2f must be >= 1 (theta={theta}, f={f})")
-    s = jnp.sort(selected, axis=0)  # (theta, ...)
-    med = s[(theta - 1) // 2]       # 1-D medoid: lower-middle of sorted vals
-    if beta == theta:
-        return jnp.mean(s, axis=0)
-    absdev = jnp.abs(s - med[None])
-    zeros = jnp.zeros_like(s[:1])
-    cd = jnp.concatenate([zeros, jnp.cumsum(absdev, axis=0)], axis=0)
-    cv = jnp.concatenate([zeros, jnp.cumsum(s, axis=0)], axis=0)
-    n_win = theta - beta + 1
-    win_dev = cd[beta:] - cd[:n_win]  # (n_win, ...): sum |x - med| per window
-    win_sum = cv[beta:] - cv[:n_win]  # (n_win, ...): sum x per window
-    w = jnp.argmin(win_dev, axis=0)   # (...)
-    best = jnp.take_along_axis(win_sum, w[None], axis=0)[0]
-    return best / beta
+    return bulyan_window(oe_sort_rows([selected[i] for i in range(theta)]),
+                         f)
 
 
 def coordinate_phase_ref(selected: jnp.ndarray, f: int) -> jnp.ndarray:
     """Literal transcription of the paper's formula (argsort of |x - med|);
-    independent oracle for the windowed implementation and the Pallas
-    kernel.  Ties (measure-zero for float inputs) may resolve differently.
+    independent oracle for the sorting-network implementation and the
+    Pallas kernels.  Ties (measure-zero for float inputs) may resolve differently.
     """
     theta = selected.shape[0]
     beta = theta - 2 * f

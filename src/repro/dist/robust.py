@@ -14,9 +14,10 @@ carry a leading worker axis and exploits the structure of the rules:
     so the only globally materialized object is n x n.
   * Coordinate-wise phases (cwmed, trimmed mean, Bulyan phase 2) are
     embarrassingly parallel over coordinates and run per-leaf, preserving
-    each leaf's sharding.  ``coordinate_phase_nd`` additionally supports
-    windowing over the flattened trailing dims to bound the O(theta * d)
-    sort workspace.
+    each leaf's sharding.  Bulyan's is one elementwise sweep (a sorting
+    network and a prefix-sum window, ``repro.core.bulyan``);
+    ``coordinate_phase_nd`` additionally supports windowing over the
+    flattened trailing dims to cap the coordinates processed at once.
 
 This module is the *engine* only: the rule bodies themselves live behind
 the unified registry (``repro.agg`` — tree implementations in
@@ -271,14 +272,14 @@ def coordinate_phase_nd(selected: jnp.ndarray, f: int,
     Args:
       selected: ``(theta, *dims)`` stack of phase-1-selected gradients.
       f: Byzantine bound; requires ``beta = theta - 2f >= 1``.
-      window: caps the number of coordinates processed at once (the sort
-        + two cumsums need O(theta * window) workspace); ``None``
-        processes every coordinate in one shot, preserving the input's
-        sharding.
+      window: caps the number of coordinates processed at once, one
+        ``coordinate_phase`` sweep per chunk of at most ``window``
+        coordinates; ``None`` processes every coordinate in one shot,
+        preserving the input's sharding.
 
     Returns:
       ``(*dims,)`` — per coordinate, the mean of the beta values closest
-      to the median (the contiguous-window argmin form).
+      to the median (the sorting-network + prefix-sum window form).
     """
     theta = selected.shape[0]
     beta = theta - 2 * f
@@ -395,9 +396,11 @@ def distributed_aggregate(tree: Any, f: int, gar: str = "bulyan-krum", *,
             out, new_state = rule.tree_fn(ctx, state)
         else:
             out = rule.tree_fn(ctx)
+        # the barrier materializes the aggregate here: fused into its
+        # consumers, the rule's ops would carry their scope, not ``agg``
         with named_span("coordinate"):
-            agg_leaves = [a.astype(dt)
-                          for a, dt in zip(out.leaves, out_dtypes)]
+            agg_leaves = jax.lax.optimization_barrier(
+                [a.astype(dt) for a, dt in zip(out.leaves, out_dtypes)])
 
     agg_tree = jax.tree_util.tree_unflatten(treedef, agg_leaves)
     res = DistAggResult(out.selected, out.scores)

@@ -15,6 +15,11 @@ block in registers:
     theta - beta + 1 windows — no gather, no second sort;
   * one fused pass: HBM traffic = read theta*block_d, write block_d.
 
+The body is ``repro.core.bulyan``'s own (``oe_sort_rows`` +
+``bulyan_window``, re-exported by ``repro.kernels.common``): the XLA
+coordinate phase runs the same code on whole rows, so the two agree
+bitwise.
+
 Grid = (d / block_d,); blocks are fully independent (embarrassingly parallel
 over coordinates — the same fact that lets the distributed runtime shard
 this phase over the `model` mesh axis).
@@ -32,10 +37,6 @@ from repro.kernels.common import (bulyan_window, oe_sort_rows,
                                   resolve_interpret)
 
 __all__ = ["bulyan_select"]
-
-# historic private alias: the sort network now lives in
-# repro.kernels.common (coord_stats and fused_agg share it)
-_oe_sort_rows = oe_sort_rows
 
 
 def _make_kernel(theta: int, f: int):

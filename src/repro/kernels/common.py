@@ -10,6 +10,11 @@ f-trimmed mean).  They used to be duplicated — or imported sideways,
 kernel deepen the chain.  This module is the single home: kernels import
 *down* into ``common`` only, never into each other.
 
+The sorting network and Bulyan's window (``oe_sort_rows``,
+``bulyan_window``) are defined in ``repro.core.bulyan``, whose XLA
+coordinate phase is built from them, and re-exported here: the kernels
+and the plain-jit path run one body.
+
 Every helper is shape-polymorphic over "rows": a list of equally-shaped
 arrays treated as axis 0 of a (rows, ...) stack.  Inside a kernel the
 rows are ``(block_d,)`` lane vectors; the same code runs on full
@@ -22,6 +27,8 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.bulyan import bulyan_window, oe_sort_rows
 
 __all__ = ["bulyan_window", "coord_median", "coord_trimmed_mean",
            "oe_sort_rows", "resolve_interpret"]
@@ -41,78 +48,6 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)
-
-
-def oe_sort_rows(rows: List[jnp.ndarray]) -> List[jnp.ndarray]:
-    """Odd-even transposition sort across a list of rows (axis 0).
-
-    Fully unrolled for the static row count (worker counts are <= a few
-    dozen): no data-dependent control flow, exactly ``m * (m - 1) / 2``
-    min/max pairs on the row vectors — the TPU-safe substitute for
-    ``jnp.sort(axis=0)`` inside a kernel body.
-
-    Args:
-      rows: list of equally-shaped arrays, one per row of the stack
-        being sorted (``(block_d,)`` lane vectors inside a kernel).
-
-    Returns:
-      New list with the rows sorted ascending per element (the inputs
-      are not mutated).
-    """
-    m = len(rows)
-    rows = list(rows)
-    for p in range(m):
-        for i in range(p % 2, m - 1, 2):
-            a, b = rows[i], rows[i + 1]
-            rows[i] = jnp.minimum(a, b)
-            rows[i + 1] = jnp.maximum(a, b)
-    return rows
-
-
-def bulyan_window(rows: List[jnp.ndarray], f: int) -> jnp.ndarray:
-    """Bulyan's coordinate phase on an already-sorted row list.
-
-    Per element: the mean of the ``beta = theta - 2f`` sorted values
-    closest to the median.  The beta-closest set is a *contiguous
-    window* of the sorted order, so it reduces to prefix sums plus an
-    unrolled argmin over ``theta - beta + 1`` windows (first-window
-    tiebreak) — no gather, no second sort.
-
-    Args:
-      rows: ``theta`` sorted rows (ascending per element), e.g. the
-        output of :func:`oe_sort_rows`.
-      f: Byzantine bound; requires ``beta = theta - 2f >= 1``.
-
-    Returns:
-      One row: per element, the best window mean.
-    """
-    theta = len(rows)
-    beta = theta - 2 * f
-    med = rows[(theta - 1) // 2]
-
-    if beta == theta:
-        acc = rows[0]
-        for r in rows[1:]:
-            acc = acc + r
-        return acc / beta
-
-    # prefix sums of sorted values and |sorted - med|
-    pref_v = [jnp.zeros_like(med)]
-    pref_d = [jnp.zeros_like(med)]
-    for r in rows:
-        pref_v.append(pref_v[-1] + r)
-        pref_d.append(pref_d[-1] + jnp.abs(r - med))
-
-    n_win = theta - beta + 1
-    best_dev = pref_d[beta] - pref_d[0]
-    best_sum = pref_v[beta] - pref_v[0]
-    for w in range(1, n_win):
-        dev = pref_d[w + beta] - pref_d[w]
-        s = pref_v[w + beta] - pref_v[w]
-        take = dev < best_dev                      # first-window tiebreak
-        best_dev = jnp.where(take, dev, best_dev)
-        best_sum = jnp.where(take, s, best_sum)
-    return best_sum / beta
 
 
 def coord_median(rows: List[jnp.ndarray]) -> jnp.ndarray:

@@ -222,3 +222,29 @@ class TestCoordinatePhaseWindow:
         for a, w in zip(jax.tree_util.tree_leaves(a_win),
                         jax.tree_util.tree_leaves(a_full)):
             np.testing.assert_allclose(a, w, rtol=1e-6, atol=1e-7)
+
+
+class TestCoordinatePhaseLowering:
+    @pytest.mark.parametrize("gar", ["bulyan-krum", "bulyan-geomed"])
+    def test_selected_rows_taken_by_dynamic_slice(self, gar):
+        """The selected workers' rows reach the coordinate phase as
+        dynamic row slices of each leaf: no op that gathers, sorts or
+        cumulatively sums touches a leaf-sized operand.  (The selection
+        itself may gather and sort its (n, n) matrix and index vectors;
+        the leaves' trailing dims, 13x29 and 31, appear nowhere there.)"""
+        n, f = 7, 1
+        theta = n - 2 * f
+        tree = {"w": jax.ShapeDtypeStruct((n, 13, 29), jnp.float32),
+                "b": jax.ShapeDtypeStruct((n, 31), jnp.float32)}
+        text = jax.jit(lambda t: distributed_aggregate(t, f, gar)[0]).lower(
+            tree).as_text()
+        lines = text.splitlines()
+        for op in ("stablehlo.gather", "stablehlo.sort", "reduce_window",
+                   "stablehlo.while"):
+            hits = [l for l in lines if op in l
+                    and ("13x29" in l or "x31x" in l or "<31x" in l)]
+            assert not hits, hits
+        for row in ("tensor<1x13x29xf32>", "tensor<1x31xf32>"):
+            slices = [l for l in lines if "stablehlo.dynamic_slice" in l
+                      and l.rstrip().endswith(row)]
+            assert len(slices) == theta, (row, len(slices))

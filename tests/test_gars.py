@@ -129,6 +129,65 @@ class TestBulyan:
         assert bool(jnp.all(jnp.isfinite(res.gradient)))
 
 
+def _legacy_coordinate_phase(selected, f):
+    """The coordinate phase as it was before the sorting network: an XLA
+    sort, cumulative sums and a ``take_along_axis`` of the best window
+    (kept here as a second oracle)."""
+    theta = selected.shape[0]
+    beta = theta - 2 * f
+    s = jnp.sort(selected, axis=0)
+    med = s[(theta - 1) // 2]
+    if beta == theta:
+        return jnp.mean(s, axis=0)
+    zeros = jnp.zeros_like(s[:1])
+    cd = jnp.concatenate([zeros, jnp.cumsum(jnp.abs(s - med[None]), axis=0)])
+    cv = jnp.concatenate([zeros, jnp.cumsum(s, axis=0)])
+    n_win = theta - beta + 1
+    w = jnp.argmin(cd[beta:] - cd[:n_win], axis=0)
+    best = jnp.take_along_axis(cv[beta:] - cv[:n_win], w[None], axis=0)[0]
+    return best / beta
+
+
+def _tied_stack(kind, theta, shape):
+    """A (theta, *shape) stack; ``kind`` plants ties the sort must keep."""
+    x = jax.random.normal(jax.random.PRNGKey(theta), (theta,) + shape)
+    if kind == "duplicate_rows":
+        x = x.at[1].set(x[0]).at[theta - 1].set(x[2])
+    elif kind == "constant_coordinate":
+        x = x.at[:, 0].set(0.5)
+    elif kind == "signed_zeros":
+        x = x.at[0].set(0.0).at[theta - 1].set(-0.0)
+        x = x.at[:, 0].set(jnp.where(jnp.arange(theta) % 2, 0.0, -0.0)
+                           .reshape((theta,) + (1,) * (len(shape) - 1)))
+    return x
+
+
+@pytest.mark.parametrize("kind", ["random", "duplicate_rows",
+                                  "constant_coordinate", "signed_zeros"])
+@pytest.mark.parametrize("shape", [(256,), (4, 8, 16)])
+@pytest.mark.parametrize("theta,f", [(3, 1), (5, 1), (7, 2), (9, 3),
+                                     (21, 9)])
+def test_coordinate_phase_matches_oracles(theta, f, shape, kind):
+    sel = _tied_stack(kind, theta, shape)
+    got = jax.jit(coordinate_phase, static_argnums=1)(sel, f)
+    assert got.shape == shape
+    np.testing.assert_allclose(got, coordinate_phase_ref(sel, f),
+                               rtol=1e-5, atol=1e-6)
+    legacy = jax.jit(_legacy_coordinate_phase, static_argnums=1)(sel, f)
+    np.testing.assert_array_max_ulp(np.asarray(got), np.asarray(legacy),
+                                    maxulp=1)
+
+
+@pytest.mark.parametrize("theta,f", [(5, 1), (21, 9)])
+def test_coordinate_phase_lowers_without_gather_sort_or_cumsum(theta, f):
+    """The coordinate phase is elementwise work along the worker axis:
+    no gather, no sort and no cumulative sum (``reduce_window``)."""
+    sel = jax.ShapeDtypeStruct((theta, 64, 33), jnp.float32)
+    text = jax.jit(coordinate_phase, static_argnums=1).lower(sel, f).as_text()
+    for op in ("stablehlo.gather", "stablehlo.sort", "reduce_window"):
+        assert op not in text, op
+
+
 class TestNoByzantineBehaviour:
     @pytest.mark.parametrize("name", ["krum", "geomed", "cwmed",
                                       "trimmed_mean", "bulyan-krum",

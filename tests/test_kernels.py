@@ -75,6 +75,34 @@ def test_block_size_invariance():
         np.testing.assert_allclose(outs[0], o, rtol=1e-6)
 
 
+@pytest.mark.parametrize("theta,f", [(3, 0), (5, 0), (3, 1), (5, 1),
+                                     (7, 2), (9, 3)])
+def test_bulyan_select_equals_coordinate_phase_bitwise(theta, f):
+    """The kernel and the XLA coordinate phase run one body (the sorting
+    network and window of ``repro.core.bulyan``), so they agree bitwise,
+    padded tail tile included; with f = 0 both are the in-order mean of
+    the sorted values."""
+    from repro.core.bulyan import coordinate_phase
+    s = jax.random.normal(jax.random.fold_in(KEY, theta), (theta, 300))
+    out = bulyan_select(s, f, block_d=128, interpret=True)
+    want = jax.jit(coordinate_phase, static_argnums=1)(s, f)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_common_reexports_core_sort_network():
+    """The kernels' shared primitives still import from
+    ``repro.kernels.common``; the sort network and window are
+    ``repro.core.bulyan``'s own objects, defined once."""
+    from repro.core import bulyan as core_bulyan
+    from repro.kernels.common import (bulyan_window, coord_median,
+                                      coord_trimmed_mean, oe_sort_rows,
+                                      resolve_interpret)
+    assert oe_sort_rows is core_bulyan.oe_sort_rows
+    assert bulyan_window is core_bulyan.bulyan_window
+    assert callable(coord_median) and callable(coord_trimmed_mean)
+    assert resolve_interpret(True) is True
+
+
 def test_ops_wrappers_dispatch():
     g = jax.random.normal(KEY, (9, 300))
     np.testing.assert_allclose(
