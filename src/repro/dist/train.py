@@ -28,9 +28,10 @@ import jax.numpy as jnp
 from repro.agg.specs import AggSpec
 from repro.agg.state import init_state
 from repro.dist.robust import distributed_aggregate, inject_byzantine
-from repro.models import forward
 from repro.models.config import ModelConfig
-from repro.obs.schema import core_metrics, global_norm, selection_weight
+from repro.models.transformer import forward_with_loads
+from repro.obs.schema import (core_metrics, global_norm, moe_metrics,
+                              selection_weight)
 from repro.obs.trace import named_span
 from repro.optim import Optimizer
 
@@ -68,17 +69,22 @@ def init_agg_state(spec: AggSpec, params, n_workers: int):
     return init_state(rule, template, flat=False)
 
 
-def make_loss_fn(cfg: ModelConfig, impl: str = "auto") -> Callable:
+def make_loss_fn(cfg: ModelConfig, impl: str = "auto",
+                 with_loads: bool = False) -> Callable:
     """Token-level cross-entropy (fp32 logsumexp) plus the model's aux
-    loss (MoE load balancing).  ``loss_fn(params, tokens, labels, extra)``.
+    loss (MoE load balancing).  ``loss_fn(params, tokens, labels, extra)``;
+    with ``with_loads`` it returns ``(loss, loads)``, the dropless expert
+    layers' loads (``forward_with_loads``), for ``has_aux``.
     """
 
     def loss_fn(params, tokens, labels, extra=None):
-        logits, aux = forward(params, cfg, tokens, extra, impl=impl)
+        logits, aux, loads = forward_with_loads(params, cfg, tokens, extra,
+                                                impl=impl)
         logits = logits.astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
         ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        return jnp.mean(logz - ll) + aux
+        loss = jnp.mean(logz - ll) + aux
+        return (loss, loads) if with_loads else loss
 
     return loss_fn
 
@@ -116,8 +122,13 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
     step mesh-agnostic exactly as before — sharding enters via the
     input/output shardings the caller jits with.
     """
-    loss_fn = make_loss_fn(cfg, impl)
-    vg = jax.value_and_grad(loss_fn)
+    vgl = jax.value_and_grad(make_loss_fn(cfg, impl, with_loads=True),
+                             has_aux=True)
+    # a dropless expert layer's grouped matmul cannot be batched over
+    # workers that route apart (``moe.moe_dropless``): one after another
+    per_worker = ((lambda fn, *xs: jax.lax.map(lambda a: fn(*a), xs))
+                  if cfg.moe_impl == "dropless" else
+                  (lambda fn, *xs: jax.vmap(fn)(*xs)))
     rule = spec.rule()
     stateful = rule.stateful
     reputed = "reputation" in rule.state_fields
@@ -135,12 +146,12 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
         # the aggregation opens ``agg/...`` of its own
         with named_span("train/grads"):
             if extra is None:
-                losses, grads = jax.vmap(
-                    lambda t, l: vg(params, t, l))(tokens, labels)
+                (losses, loads), grads = per_worker(
+                    lambda t, l: vgl(params, t, l), tokens, labels)
             else:
-                losses, grads = jax.vmap(
-                    lambda t, l, e: vg(params, t, l, e))(tokens, labels,
-                                                         extra)
+                (losses, loads), grads = per_worker(
+                    lambda t, l, e: vgl(params, t, l, e), tokens, labels,
+                    extra)
 
         if spec.attack != "none" and f > 0:
             with named_span("train/inject"):
@@ -172,7 +183,7 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
                     # agreement-with-the-aggregate update — the only signal
                     # a colluding majority cannot vote on
                     aux = tuple(spec.aux_batch)
-                    _, clean = vg(params, *aux)
+                    _, clean = vgl(params, *aux)
                     scores = tree_reputation_scores(
                         jax.tree_util.tree_leaves(grads),
                         jax.tree_util.tree_leaves(clean))
@@ -203,6 +214,8 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
                 agg_dev=global_norm(dev),
                 byz_weight=selection_weight(res.selected, n_h),
                 step_scale=step_scale if reputed else None)
+            if loads.size:
+                metrics.update(moe_metrics(loads))
         return new_params, new_state, metrics, new_agg_state
 
     if stateful:

@@ -3,8 +3,10 @@ bidirectional / cross variants, a naive einsum path and a blockwise
 (flash-style, online-softmax) path, plus single-token decode against a KV
 cache.
 
-Shapes: q (B, Sq, Hq, D); k, v (B, Sk, Hkv, D) with Hq = G * Hkv.
-Softmax statistics are fp32 regardless of input dtype.
+Shapes: q (B, Sq, Hq, D); k (B, Sk, Hkv, D), v (B, Sk, Hkv, Dv) with
+Hq = G * Hkv (``Dv`` may differ from ``D``, as in latent attention; the
+scores are scaled by ``D``).  Softmax statistics are fp32 regardless of
+input dtype.
 """
 from __future__ import annotations
 
@@ -19,16 +21,25 @@ NEG_INF = -1e30
 
 # -- RoPE ---------------------------------------------------------------------
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, D), positions: (S,) or broadcastable."""
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         interleave: bool = False) -> jnp.ndarray:
+    """x: (..., S, H, D), positions: (S,) or broadcastable.
+
+    Rotates the pairs ``(i, i + D/2)`` (half-split), or with ``interleave``
+    the pairs ``(2i, 2i + 1)``, by ``position / theta^(2i/D)``."""
     d = x.shape[-1]
     half = d // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[..., None].astype(jnp.float32) * freqs  # (S, half)
     cos = jnp.cos(ang)[..., None, :]  # (S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
+    xf = x.astype(jnp.float32)
+    if interleave:
+        pairs = xf.reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
+    xf1, xf2 = xf[..., :half], xf[..., half:]
     return jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
         axis=-1).astype(x.dtype)
@@ -78,7 +89,7 @@ def attention_naive(q, k, v, *, kind: str = "attn", window: int = 0,
     scores = scores + bias[None, None, None]
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", w.astype(v.dtype), v)
-    return out.reshape(b, sq, hq, d)
+    return out.reshape(b, sq, hq, v.shape[-1])
 
 
 # -- blockwise (flash-style) path ----------------------------------------------
@@ -93,7 +104,7 @@ def attention_blockwise(q, k, v, *, kind: str = "attn", window: int = 0,
     sliding-window / chunked layouts), cutting both FLOPs and memory traffic.
     """
     b, sq, hq, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
     g = hq // hkv
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -127,7 +138,7 @@ def attention_blockwise(q, k, v, *, kind: str = "attn", window: int = 0,
                 lo_blk = max(0, ((q_lo // chunk) * chunk) // block_k)
         m = jnp.full((b, block_q, hkv, g), NEG_INF, jnp.float32)
         l = jnp.zeros((b, block_q, hkv, g), jnp.float32)
-        acc = jnp.zeros((b, block_q, hkv, g, d), jnp.float32)
+        acc = jnp.zeros((b, block_q, hkv, g, dv), jnp.float32)
 
         def kv_step(carry, ik):
             m, l, acc = carry
@@ -150,12 +161,12 @@ def attention_blockwise(q, k, v, *, kind: str = "attn", window: int = 0,
 
         n_blocks = hi_blk - lo_blk
         if n_blocks <= 0:
-            outs.append(jnp.zeros((b, block_q, hq, d), q.dtype))
+            outs.append(jnp.zeros((b, block_q, hq, dv), q.dtype))
             continue
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m, l, acc), lo_blk + jnp.arange(n_blocks))
         o = acc / jnp.maximum(l[..., None], 1e-30)
-        outs.append(o.reshape(b, block_q, hq, d).astype(q.dtype))
+        outs.append(o.reshape(b, block_q, hq, dv).astype(q.dtype))
     out = jnp.concatenate(outs, axis=1)
     return out[:, :sq]
 

@@ -21,6 +21,21 @@ from repro.models.config import ModelConfig
 from repro.models.layers import _dtype
 
 
+def check_decodable(cfg: ModelConfig) -> None:
+    """Refuse a model this path cannot serve: its caches are full k/v per
+    head, and a latent-attention (``mla``) model needs a latent cache (the
+    normed latent ``c`` and the shared ``k_rope`` per position), which is
+    not implemented; leading dense layers have no cache slot either."""
+    if "mla" in cfg.layer_pattern:
+        raise NotImplementedError(
+            "mla slots cannot decode: the latent KV cache (normed latent c "
+            "and shared k_rope per position) is not implemented; "
+            "latent-attention models are train-only")
+    if cfg.dense_lead:
+        raise NotImplementedError(
+            "leading dense layers (dense_lead) have no decode cache")
+
+
 def slot_cache_len(cfg: ModelConfig, slot: str, cache_len: int) -> int:
     if slot == "swa" and cfg.window > 0:
         return min(cfg.window, cache_len)
@@ -49,6 +64,7 @@ def _init_slot_cache(cfg: ModelConfig, slot: str, batch: int,
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int) -> dict:
+    check_decodable(cfg)
     dtype = _dtype(cfg.param_dtype)
     periods = {}
     for j, slot in enumerate(cfg.layer_pattern):
@@ -132,6 +148,7 @@ def decode_step(params, cfg: ModelConfig, cache: dict, token: jnp.ndarray,
                 pos) -> Tuple[jnp.ndarray, dict]:
     """token: (B, 1) int32; pos: scalar or (B,) per-sequence positions.
     Returns (logits (B, 1, V), new_cache)."""
+    check_decodable(cfg)
     x = layers.embed(params["embed"], token)
 
     def body(x, xs):
@@ -289,6 +306,7 @@ def verify_step(params, cfg: ModelConfig, cache: dict, tokens: jnp.ndarray,
       ``(logits (B, S, V), new_cache)`` — the cache gains the block's
       ``S`` key/value entries per attention layer.
     """
+    check_decodable(cfg)
     x = layers.embed(params["embed"], tokens)
 
     def body(x, xs):
@@ -366,7 +384,7 @@ def _prefill_slot(p, x, cfg: ModelConfig, slot: str, positions, enc_out,
                        ).reshape(b, hx.shape[1], cfg.n_kv_heads, hd)
         cache["xv"] = (hx @ p["xatt"]["wv"] + p["xatt"].get("bv", 0.0)
                        ).reshape(b, hx.shape[1], cfg.n_kv_heads, hd)
-    x, _ = _apply_layer(p, x, cfg, slot, 0, positions, enc_out, impl)
+    x, _, _ = _apply_layer(p, x, cfg, slot, 0, positions, enc_out, impl)
     return x, cache
 
 
@@ -375,6 +393,7 @@ def prefill(params, cfg: ModelConfig, tokens: jnp.ndarray,
             impl: str = "auto") -> Tuple[jnp.ndarray, dict]:
     """Full-sequence forward that also returns populated decode caches.
     ``cache_len`` defaults to the sequence length."""
+    check_decodable(cfg)
     from repro.models.transformer import _run_encoder
     b, s = tokens.shape
     cache_len = cache_len or s

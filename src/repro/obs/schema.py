@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["METRIC_SCHEMA", "async_extras", "core_metrics",
-           "global_norm", "selection_weight"]
+           "global_norm", "moe_metrics", "selection_weight"]
 
 #: canonical metric catalog: name -> (paths, description).  ``paths`` is
 #: a ``/``-joined subset of {sync, async} x {flat, dist}; ``all`` means
@@ -44,6 +44,14 @@ METRIC_SCHEMA: Dict[str, tuple] = {
     "staleness_excess": ("async", "max overshoot beyond the bounded-"
                                   "staleness bound tau (0 = bound held)"),
     "delivered": ("async", "worker slots refreshed this step"),
+    "moe_held_tokens": ("moe", "(token, slot) pairs routed to the held "
+                               "experts, summed over workers and dropless "
+                               "expert layers"),
+    "moe_load_max_over_mean": ("moe", "largest (worker, layer, held "
+                                      "expert) load over the mean"),
+    "moe_idle_experts": ("moe", "(worker, layer, held expert) triples no "
+                                "token reached: their gradient rows are "
+                                "exactly zero"),
 }
 
 
@@ -110,6 +118,24 @@ def core_metrics(*, loss, grad_norm, agg_dev, byz_weight,
         metrics["step_scale"] = step_scale
     assert set(metrics) <= set(METRIC_SCHEMA)
     return metrics
+
+
+def moe_metrics(loads: jnp.ndarray) -> Dict:
+    """The dropless expert layers' load metrics.
+
+    Args:
+      loads: ``(n_workers, n_layers, E_held)`` int (token, slot) pairs
+        per held expert (``forward_with_loads``, stacked over workers).
+
+    Returns:
+      Dict with ``moe_held_tokens``, ``moe_load_max_over_mean`` and
+      ``moe_idle_experts``.
+    """
+    f = loads.astype(jnp.float32)
+    return {"moe_held_tokens": jnp.sum(f),
+            "moe_load_max_over_mean": jnp.max(f) / jnp.maximum(
+                jnp.mean(f), 1e-9),
+            "moe_idle_experts": jnp.sum(loads == 0).astype(jnp.float32)}
 
 
 def async_extras(staleness: jnp.ndarray, excess: jnp.ndarray,
