@@ -282,7 +282,7 @@ def serve_run(stacked, cfg, spec, prompts) -> dict:
                            cache_len=PROMPT + NEW_TOKENS + SPEC_K,
                            ensemble=spec)
     seen = []
-    engine._ens_prefill = record(engine._ens_prefill, seen)
+    engine._prefill = record(engine._prefill, seen)
     if spec.speculative_k:
         engine._verify = record(engine._verify, seen)
     else:

@@ -6,6 +6,8 @@ every active slot (a single jit'd ``decode_step`` on the full batch).  New
 requests are admitted into free slots via per-slot prefill.  This is the
 "serve a small model with batched requests" driver of deliverable (b) and
 exercises caches/positions exactly as the decode dry-run shapes do.
+Admission's prefill is one jit'd program too, compiled once per prompt
+length and cached by shape, so clients should bucket prompt lengths.
 
 Each slot carries its own position counter (mixed-length batching ropes
 and cache-writes per slot).  Admission is continuous: requests queue via
@@ -42,12 +44,29 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.models import decode_step, init_cache, prefill
 from repro.models.config import ModelConfig
 from repro.obs.trace import count_compiles, host_span
 
 __all__ = ["Request", "ServingEngine"]
+
+
+def _jit_with_first_token(prefill_step):
+    """Jit ``prefill_step(params, tokens) -> (last_logits, cache, ...)``
+    with the greedy first token appended to its outputs.
+
+    ``last_logits`` is ``(1, vocab)``; its argmax (``lax.argmax``, the
+    tie rule of ``jnp.argmax``) runs in the same program, so admission
+    reads back one int32.  jit's cache keys the program on the prompt's
+    shape: one compile per prompt length.
+    """
+    def step(params, tokens):
+        out = prefill_step(params, tokens)
+        return (*out, lax.argmax(out[0][0], 0, jnp.int32))
+
+    return jax.jit(step)
 
 
 @dataclasses.dataclass
@@ -88,7 +107,9 @@ class ServingEngine:
     request's wait between :meth:`submit` and :meth:`admit`), and
     ``compiles`` / ``compile_s`` (the JAX compile work done inside
     :meth:`admit` and :meth:`step`, see
-    :func:`repro.obs.trace.count_compiles`).  Admission and decoding run
+    :func:`repro.obs.trace.count_compiles`).  The prefill is compiled
+    once per prompt length, so after every length has been seen an
+    admission adds no compile.  Admission and decoding run
     under host spans (``serve/admit``, ``serve/step`` and their phases,
     :func:`repro.obs.trace.host_span`) that a profiler trace shows
     beside the device ops.
@@ -117,6 +138,12 @@ class ServingEngine:
             self.cache = init_cache(cfg, n_slots, cache_len)
             self._decode = jax.jit(
                 lambda p, c, t, pos: decode_step(p, cfg, c, t, pos))
+
+            def last_logits(p, t):
+                logits, cache = prefill(p, cfg, t, cache_len=cache_len)
+                return logits[:, -1], cache
+
+            self._prefill = _jit_with_first_token(last_logits)
             return
         # -- ensemble mode ----------------------------------------------------
         from repro.dist.serve_robust import (init_ensemble_state,
@@ -134,8 +161,8 @@ class ServingEngine:
             ensemble, self.n_replicas, n_slots, cfg.vocab_size)
         self._decode = jax.jit(
             make_robust_serve_step(cfg, ensemble, mesh=mesh))
-        self._ens_prefill = make_robust_prefill_step(
-            cfg, ensemble, cache_len=cache_len, mesh=mesh)
+        self._prefill = _jit_with_first_token(make_robust_prefill_step(
+            cfg, ensemble, cache_len=cache_len, mesh=mesh))
         # -- speculative mode -------------------------------------------------
         k = int(getattr(ensemble, "speculative_k", 0) or 0)
         if k < 1:
@@ -213,18 +240,12 @@ class ServingEngine:
             self.counters["queue_s"] += time.perf_counter() - req.submitted_at
         self.counters["admissions"] += 1
         req.generated = []
-        tokens = jnp.asarray(req.prompt, jnp.int32)[None, :]
+        tokens = np.asarray(req.prompt, np.int32)[None, :]
         with host_span("serve/admit/prefill", rid=req.rid):
-            if self.ensemble is None:
-                logits, slot_cache = prefill(self.params, self.cfg, tokens,
-                                             cache_len=self.cache_len)
-                last = logits[0, -1]
-            else:
-                agg_logits, slot_cache, _ = self._ens_prefill(self.params,
-                                                              tokens)
-                last = agg_logits[0]
+            out = self._prefill(self.params, tokens)
+            slot_cache = out[1]
         with host_span("serve/admit/first_token", rid=req.rid):
-            first = int(jnp.argmax(last))
+            first = int(out[-1])
         with host_span("serve/admit/splice", rid=req.rid):
             self._splice_cache(slot, slot_cache)
             if self.spec_k:

@@ -7,7 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.agg import AggSpec
 from repro.configs import get_reduced
+from repro.dist.serve_robust import (make_robust_prefill_step,
+                                     poison_replicas, replicate_params)
 from repro.models import decode_step, forward, init_cache, init_model, prefill
 from repro.serving import Request, ServingEngine
 
@@ -112,3 +115,71 @@ def test_engine_mixed_length_slots_are_position_correct():
                   max_steps=20)
     assert got[0] == want[0]
     assert got[1] == want[1]
+
+
+def _admission_engine(mode):
+    """A 3-slot engine over a reduced model: one parameter set, or seven
+    jittered replicas with the last sign-flipped (Bulyan-Krum, f=1)."""
+    cfg = get_reduced("llama3_2_3b")
+    params = init_model(KEY, cfg)
+    if mode == "plain":
+        return ServingEngine(params, cfg, n_slots=3, cache_len=32), cfg
+    stacked = poison_replicas(
+        replicate_params(params, 7, jitter=1e-2, key=jax.random.PRNGKey(1)),
+        1, "signflip", scale=10.0)
+    spec = AggSpec(f=1, gar="bulyan-krum")
+    return ServingEngine(stacked, cfg, n_slots=3, cache_len=32,
+                         ensemble=spec), cfg
+
+
+@pytest.mark.parametrize("mode", ["plain", "ensemble"])
+def test_jitted_admission_matches_the_unjitted_prefill(mode):
+    """Admission's compiled prefill gives the first token and the spliced
+    cache of the model's prefill (or the robust prefill step) run op by
+    op."""
+    eng, cfg = _admission_engine(mode)
+    prompt = (np.arange(9) * 7 + 3) % cfg.vocab_size
+    tokens = jnp.asarray(prompt, jnp.int32)[None]
+    if mode == "plain":
+        logits, ref_cache = prefill(eng.params, cfg, tokens,
+                                    cache_len=eng.cache_len)
+        last = logits[0, -1]
+    else:
+        agg, ref_cache, _ = make_robust_prefill_step(
+            cfg, eng.ensemble, cache_len=eng.cache_len)(eng.params, tokens)
+        last = agg[0]
+    slot = 1
+    eng.active[0] = Request(rid=-1, prompt=prompt, max_new_tokens=1)
+    before = eng.cache
+    req = Request(rid=0, prompt=prompt, max_new_tokens=4)
+    assert eng.admit(req)
+    assert eng.active[slot] is req
+    assert req.generated == [int(jnp.argmax(last))]
+    assert eng.last_token[slot] == req.generated[0]
+    want = ServingEngine._spliced(before, slot, ref_cache,
+                                  replicated=mode == "ensemble")
+    for got, ref in zip(jax.tree_util.tree_leaves(eng.cache),
+                        jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(got, ref, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("mode", ["plain", "ensemble"])
+def test_admission_compiles_once_per_prompt_length(mode):
+    """A prompt length already seen admits with no compile, in any slot;
+    a new length compiles its own prefill."""
+    eng, cfg = _admission_engine(mode)
+
+    def compiles_for(length, rid):
+        before = eng.counters["compiles"]
+        assert eng.admit(Request(rid=rid, prompt=np.arange(length)
+                                 % cfg.vocab_size, max_new_tokens=2))
+        return eng.counters["compiles"] - before
+
+    assert compiles_for(5, 0) >= 1
+    assert compiles_for(5, 1) == 0          # same length, another slot
+    eng.active[0] = None
+    assert compiles_for(5, 2) == 0          # same length, a reused slot
+    eng.active = [None] * eng.n_slots
+    assert compiles_for(8, 3) >= 1
+    assert compiles_for(8, 4) == 0
+    assert eng.counters["admissions"] == 5
