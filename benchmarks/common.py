@@ -19,11 +19,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.agg import AggSpec
 from repro.data import ByzantineBatcher
 from repro.data.synthetic import cifar_like, mnist_like
 from repro.models import simple
 from repro.optim import fading_lr, get_optimizer
-from repro.training import ByzantineSpec, ByzantineTrainer
+from repro.training import ByzantineTrainer
 
 
 def mnist_loss(params, x, y):
@@ -63,8 +64,8 @@ def run_experiment(*, kind: str, gar: str, attack: str, n_honest: int,
     else:
         params = simple.init_cifar_cnn(key)
         loss = cifar_loss
-    spec = ByzantineSpec(n_workers=n_honest + f, f=f, gar=gar,
-                         attack=attack, attack_kwargs=attack_kwargs)
+    spec = AggSpec(n_workers=n_honest + f, f=f, gar=gar,
+                   attack=attack, attack_kwargs=attack_kwargs)
     opt = get_optimizer("sgd", fading_lr(eta0, r_eta))
     trainer = ByzantineTrainer(loss, params, opt, spec, seed=seed)
     eval_fn = make_eval(kind, noise=noise)

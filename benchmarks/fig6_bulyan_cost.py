@@ -24,10 +24,11 @@ def main(steps: int = 60) -> None:
         from repro.data import ByzantineBatcher
         from repro.models import simple
         from repro.optim import fading_lr, get_optimizer
-        from repro.training import ByzantineSpec, ByzantineTrainer
+        from repro.agg import AggSpec
+        from repro.training import ByzantineTrainer
         from benchmarks.common import make_eval, mnist_loss
-        spec = ByzantineSpec(n_workers=39, f=0, gar="bulyan-krum",
-                             attack="none", declared_f=9)
+        spec = AggSpec(n_workers=39, f=0, gar="bulyan-krum",
+                       attack="none", declared_f=9)
         tr = ByzantineTrainer(mnist_loss,
                               simple.init_mnist_mlp(jax.random.PRNGKey(1)),
                               get_optimizer("sgd", fading_lr(1.0, 10000)),

@@ -32,14 +32,14 @@ from benchmarks.common import emit, make_eval, mnist_loss
 from repro.data import ByzantineBatcher
 from repro.models import simple
 from repro.optim import fading_lr, get_optimizer
-from repro.training import (AsyncByzantineTrainer, ByzantineSpec,
-                            ByzantineTrainer)
+from repro.agg import AggSpec
+from repro.training import AsyncByzantineTrainer, ByzantineTrainer
 
 
 def _train(gar: str, attack: str, tau: int, steps: int, *, n_honest=30,
            f=9, seed=1):
     n = n_honest + (f if attack != "none" else 0)
-    spec = ByzantineSpec(
+    spec = AggSpec(
         n_workers=n, f=f if attack != "none" else 0, gar=gar,
         attack=attack, async_tau=tau, seed=seed,
         attack_kwargs=(("scale", -4.0),) if attack == "stale_replay"

@@ -8,7 +8,7 @@ crosses its bound.  ``reputation-<base>`` (ByGARS-style, see
 ``repro.agg.reputation``) has a quorum constant in f: it runs at any
 attacker fraction and defends by down-weighting workers whose
 submissions disagree with a clean auxiliary-batch gradient
-(``ByzantineSpec(aux_batch=...)`` — agreement with the emitted aggregate
+(``AggSpec(aux_batch=...)`` — agreement with the emitted aggregate
 alone bootstraps wrong once the colluders own the aggregate).
 
 Rows: ``gar_reputation/<rule>_f<k>`` at f in {n/4, n/2, 3n/4} of
@@ -33,11 +33,12 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit, make_eval, mnist_loss
+from repro.agg import AggSpec
 from repro.data import ByzantineBatcher
 from repro.data.synthetic import mnist_like
 from repro.models import simple
 from repro.optim import fading_lr, get_optimizer
-from repro.training import ByzantineSpec, ByzantineTrainer
+from repro.training import ByzantineTrainer
 
 N_TOTAL = 12
 
@@ -52,7 +53,7 @@ def _train(gar: str, f: int, steps: int, seed: int = 1):
     """Train one (rule, f) cell; returns (us_per_step, accuracy)."""
     reputed = gar.startswith("reputation-")
     n_honest = N_TOTAL - f
-    spec = ByzantineSpec(
+    spec = AggSpec(
         n_workers=N_TOTAL if f else n_honest, f=f, gar=gar,
         attack="colluding_majority" if f else "none", seed=seed,
         # eps is in delta_bar units along a *unit* direction, and this
@@ -99,7 +100,7 @@ def main(steps: int = 40, seed: int = 1) -> None:
     for gar in ("average", "krum", "bulyan-krum", "reputation-krum"):
         for f in fs:
             try:
-                ByzantineSpec(n_workers=N_TOTAL, f=f, gar=gar).validate()
+                AggSpec(n_workers=N_TOTAL, f=f, gar=gar).validate()
             except ValueError:
                 # the quorum family cannot even run here — the refusal
                 # is the row (reputation-* must never land in it)

@@ -18,14 +18,14 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit, mnist_loss
+from repro.agg import AggSpec
 from repro.models import simple
 from repro.optim import get_optimizer
-from repro.training import ByzantineSpec
 from repro.training.trainer import (init_flat_agg_state,
                                     make_byzantine_step)
 
 
-def _make_timer(spec: ByzantineSpec, params, opt, x, y, reps: int):
+def _make_timer(spec: AggSpec, params, opt, x, y, reps: int):
     """Compile the flat step for ``spec``; return a us/call sampler."""
     step = jax.jit(make_byzantine_step(mnist_loss, opt, spec,
                                        attack_on=spec.attack != "none"))
@@ -78,10 +78,10 @@ def main(gars=("krum", "cwmed", "bulyan-krum"), n_workers: int = 15,
     params = simple.init_mnist_mlp(jax.random.PRNGKey(0))
     worst = 0.0
     for gar in gars:
-        spec_off = ByzantineSpec(n_workers=n_workers, f=f, gar=gar,
-                                 attack="signflip", telemetry=False)
-        spec_on = ByzantineSpec(n_workers=n_workers, f=f, gar=gar,
-                                attack="signflip", telemetry=True)
+        spec_off = AggSpec(n_workers=n_workers, f=f, gar=gar,
+                           attack="signflip", telemetry=False)
+        spec_on = AggSpec(n_workers=n_workers, f=f, gar=gar,
+                          attack="signflip", telemetry=True)
         opt = get_optimizer("sgd", 0.05)
         x, y = ByzantineBatcher("mnist", spec_off.n_honest, batch).batch(0)
         x, y = jnp.asarray(x), jnp.asarray(y)

@@ -25,8 +25,8 @@ from repro.data.synthetic import mnist_like
 from repro.launch.device import enable_compile_cache
 from repro.models import simple
 from repro.optim import fading_lr, get_optimizer
-from repro.training import (AsyncByzantineTrainer, ByzantineSpec,
-                            ByzantineTrainer)
+from repro.agg import AggSpec
+from repro.training import AsyncByzantineTrainer, ByzantineTrainer
 
 
 def loss_fn(params, x, y):
@@ -51,7 +51,7 @@ def main_async(args):
                            ("average", "stale_replay", args.f),
                            ("stale-krum", "stale_replay", args.f),
                            ("stale-bulyan-krum", "stale_replay", args.f)):
-        spec = ByzantineSpec(
+        spec = AggSpec(
             n_workers=args.n_honest + f, f=f, gar=gar, attack=attack,
             async_tau=tau,
             attack_kwargs=(("scale", -4.0), ("hold", tau + 1))
@@ -110,12 +110,12 @@ def main():
         attack = "none" if gar == "average" else "omniscient_lp"
         f = 0 if gar == "average" else args.f
         base = gar.replace("bulyan-", "")
-        spec = ByzantineSpec(n_workers=args.n_honest + f, f=f, gar=gar,
-                             attack=attack,
-                             attack_kwargs=(("gar_name", base),
-                                            ("gamma", "closed"),
-                                            ("coord", "top"),
-                                            ("margin", 0.8)))
+        spec = AggSpec(n_workers=args.n_honest + f, f=f, gar=gar,
+                       attack=attack,
+                       attack_kwargs=(("gar_name", base),
+                                      ("gamma", "closed"),
+                                      ("coord", "top"),
+                                      ("margin", 0.8)))
         tr = ByzantineTrainer(
             loss_fn, simple.init_mnist_mlp(jax.random.PRNGKey(1)),
             get_optimizer("sgd", fading_lr(args.eta0, 10000)), spec)

@@ -24,8 +24,8 @@ import numpy as np
 
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.data.synthetic import lm_batches
-from repro.dist.train import (DistByzantineSpec, init_agg_state,
-                              make_train_step)
+from repro.agg import AggSpec
+from repro.dist.train import init_agg_state, make_train_step
 from repro.launch.device import enable_compile_cache
 from repro.models import init_model
 from repro.models.config import ModelConfig
@@ -82,7 +82,7 @@ def main():
         params, start = load_checkpoint(args.ckpt, params)
         print(f"resumed from step {start}")
 
-    spec = DistByzantineSpec(f=args.f, gar=args.gar, attack=args.attack)
+    spec = AggSpec(f=args.f, gar=args.gar, attack=args.attack)
     step = jax.jit(make_train_step(cfg, spec, opt))
     # stateful GARs (buffered-*, centered_clip_momentum) carry an AggState
     agg_state = init_agg_state(spec, params, args.workers)

@@ -42,15 +42,16 @@ def _train(gar: str, attack: str, n_workers: int, f: int, steps: int,
     from repro.data import ByzantineBatcher
     from repro.models import simple
     from repro.optim import get_optimizer
-    from repro.training import ByzantineSpec, ByzantineTrainer
+    from repro.agg import AggSpec
+    from repro.training import ByzantineTrainer
 
     def loss_fn(params, x, y):
         return simple.classification_loss(
             simple.mnist_mlp_forward(params, x), y, params)
 
     kwargs = (("gar_name", gar),) if attack == "omniscient_lp" else ()
-    spec = ByzantineSpec(n_workers=n_workers, f=f, gar=gar, attack=attack,
-                         attack_kwargs=kwargs, telemetry=True)
+    spec = AggSpec(n_workers=n_workers, f=f, gar=gar, attack=attack,
+                   attack_kwargs=kwargs, telemetry=True)
     trainer = ByzantineTrainer(
         loss_fn, simple.init_mnist_mlp(jax.random.PRNGKey(seed)),
         get_optimizer("sgd", 0.05), spec, seed=seed)

@@ -54,8 +54,9 @@ which f regime) and the serving-side per-slot ``(n, batch)`` layout.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence, Tuple
+from typing import Any, Callable, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.agg.registry import AggregatorRule
@@ -63,8 +64,8 @@ from repro.agg.state import AggState
 
 __all__ = ["DEFAULT_REP_DECAY", "DEFAULT_REP_LR", "blend_stack",
            "make_reputation", "reputation_scale", "reputation_scores",
-           "step_size_multiplier", "tree_reputation_scores",
-           "update_reputation"]
+           "reputation_tail", "step_size_multiplier",
+           "tree_reputation_scores", "update_reputation"]
 
 #: EMA rate of the per-step reputation update (``rep_lr``)
 DEFAULT_REP_LR = 0.5
@@ -169,9 +170,8 @@ def step_size_multiplier(state: AggState) -> jnp.ndarray:
     trusted committee multiplies by exactly 1 (bitwise no-op), while a
     committee whose scores have collapsed shrinks the step — the
     staleness-adaptive step-size rule of Alistarh et al. folded onto the
-    same state that reweights the stack.  Threaded into
-    ``make_train_step`` / ``make_async_train_step`` (and the flat
-    trainer) when ``spec.rep_lr`` is set.
+    same state that reweights the stack.  Applied by
+    :func:`reputation_tail` when ``spec.rep_lr`` is set.
 
     Args:
       state: carried ``AggState`` with an allocated ``reputation``
@@ -181,6 +181,38 @@ def step_size_multiplier(state: AggState) -> jnp.ndarray:
       fp32 scalar in ``(0, 1]``.
     """
     return jnp.mean(reputation_scale(state))
+
+
+def reputation_tail(spec, rep: jnp.ndarray, state: AggState, agg: Any,
+                    clean_scores: Callable[[], jnp.ndarray]
+                    ) -> Tuple[AggState, Any, jnp.ndarray]:
+    """Every train step's tail after a ``reputation-*`` rule.
+
+    With ``spec.aux_batch`` set, the clean-batch scores replace the
+    rule's own agreement update of this step (ByGARS proper: a signal a
+    colluding majority cannot vote on); with ``spec.rep_lr`` set, the
+    aggregate (a pytree; a flat vector is a one-leaf tree) is scaled by
+    :func:`step_size_multiplier` in fp32.  ``rep`` is the reputation the
+    step started from, ``state`` the rule's post-aggregation state, and
+    ``clean_scores()`` — called only with ``aux_batch`` — the aggregated
+    rows' scores in the caller's representation
+    (:func:`reputation_scores` or :func:`tree_reputation_scores`).
+
+    Returns:
+      ``(state, agg, step_scale)``, the scale 1 without ``rep_lr``.
+    """
+    if spec.aux_batch is not None:
+        lr = DEFAULT_REP_LR if spec.rep_lr is None else spec.rep_lr
+        decay = DEFAULT_REP_DECAY if spec.rep_decay is None else spec.rep_decay
+        state = state._replace(reputation=update_reputation(
+            rep, clean_scores(), lr, decay))
+    step_scale = jnp.ones((), jnp.float32)
+    if spec.rep_lr:
+        step_scale = step_size_multiplier(state)
+        agg = jax.tree_util.tree_map(
+            lambda a: (a.astype(jnp.float32) * step_scale).astype(a.dtype),
+            agg)
+    return state, agg, step_scale
 
 
 def blend_stack(leaf: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:

@@ -1,11 +1,10 @@
 """The unified Byzantine-protocol spec and the shared quorum check.
 
-``repro.training.trainer.ByzantineSpec`` (single-host flat path) and
-``repro.dist.train.DistByzantineSpec`` (sharded path) used to be two
-near-duplicate dataclasses with three diverging quorum error messages
-(the third lived in ``repro.dist.robust._check_quorum``).  They are now
-one spec type, :class:`AggSpec`, kept importable under both old names,
-and one checker, :func:`check_quorum`, used by every layer.
+The single-host flat path and the sharded path used to carry two
+near-duplicate spec dataclasses with three diverging quorum error
+messages (the third lived in ``repro.dist.robust._check_quorum``).
+They are now one spec type, :class:`AggSpec`, and one checker,
+:func:`check_quorum`, used by every layer.
 
 All fields are keyword-only (every call site in the repo already was),
 so the two historic field orders can no longer conflict.
@@ -146,8 +145,7 @@ class AggSpec:
           n_workers: worker count to check against.  ``None`` falls back
             to ``self.n_workers`` (the single-host form
             ``spec.validate()``); the sharded step builders pass the
-            batch's worker axis at trace time instead (the historic
-            ``DistByzantineSpec.validate`` form).
+            batch's worker axis at trace time instead.
           distributed: when True, additionally require the rule to have
             a distributed (tree) implementation — e.g. ``bulyan-brute``
             is valid on the flat path but rejected here.  This used to

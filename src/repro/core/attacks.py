@@ -410,6 +410,10 @@ ATTACKS = {
     "colluding_majority": colluding_majority,
 }
 
+#: the delay-exploiting attacks: they read ``prev``, their own previous
+#: bus rows, which the asynchronous step bodies hand them
+DELAY_ATTACKS = ("stale_replay", "slow_drift")
+
 
 def get_attack(name: str):
     if name not in ATTACKS:

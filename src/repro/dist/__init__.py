@@ -42,8 +42,7 @@ from repro.dist.sharding import (batch_pspec, cache_shardings,
                                  ensemble_cache_shardings,
                                  ensemble_param_shardings, gram_pspec,
                                  param_shardings)
-from repro.dist.train import (DistByzantineSpec, init_agg_state,
-                              make_loss_fn, make_train_step)
+from repro.dist.train import init_agg_state, make_loss_fn, make_train_step
 from repro.dist.async_train import (GradientBus, delivery_mask,
                                     init_async_state, init_bus,
                                     make_async_train_step, resolve_tau,
@@ -56,7 +55,7 @@ from repro.dist.serve_robust import (aggregate_logits, init_ensemble_state,
                                      replicate_params, stack_replicas)
 
 __all__ = [
-    "DistAggResult", "DistByzantineSpec", "GradientBus", "aggregate_logits",
+    "DistAggResult", "GradientBus", "aggregate_logits",
     "batch_pspec", "cache_shardings", "coordinate_phase_nd",
     "delivery_mask", "distributed_aggregate", "ensemble_cache_shardings",
     "ensemble_param_shardings", "gram_pspec", "init_agg_state",

@@ -1,5 +1,9 @@
 """The asynchronous bounded-staleness Byzantine train step.
 
+The step is the synchronous step's body (``repro.dist.train``) with one
+more stage, :func:`bus_stage`, between injection and aggregation; this
+module holds the bus that stage drives.
+
 The third runtime mode (train / serve / **async-train**): instead of the
 synchronous barrier of ``repro.dist.train`` — every worker submits a
 fresh gradient every step — the master aggregates whatever a
@@ -45,7 +49,7 @@ The flat single-host reference of this runtime lives in
 """
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,15 +57,13 @@ import numpy as np
 
 from repro.agg.specs import AggSpec
 from repro.agg.state import AggState, init_state
-from repro.dist.robust import distributed_aggregate, inject_byzantine
-from repro.dist.train import make_loss_fn
-from repro.obs.schema import (async_extras, core_metrics, global_norm,
-                              selection_weight)
+from repro.dist.train import make_step_body
+from repro.obs.schema import async_extras
 from repro.optim import Optimizer
 
-__all__ = ["GradientBus", "delivery_mask", "init_async_state", "init_bus",
-           "make_async_train_step", "resolve_tau", "staleness_excess",
-           "update_bus"]
+__all__ = ["GradientBus", "bus_stage", "delivery_mask", "init_async_state",
+           "init_bus", "make_async_train_step", "resolve_tau",
+           "staleness_excess", "update_bus"]
 
 
 class GradientBus(NamedTuple):
@@ -269,144 +271,58 @@ def init_async_state(spec: AggSpec, params: Any, n_workers: int) -> AggState:
     return state
 
 
+def bus_stage(spec: AggSpec, state: AggState, rows: Any, n_h: int,
+              attacked: bool) -> Tuple[AggState, Dict]:
+    """The asynchronous runtime's stage of a train step body.
+
+    Both step bodies (the sharded ``repro.dist.train.make_step_body``,
+    the flat ``repro.training.trainer`` reference) run it between
+    injection and aggregation, then aggregate ``state.bus.grads``.
+    ``rows`` is the worker-stacked ``(n, *dims)`` pytree of this step's
+    submissions (a flat ``(n, d)`` matrix is a one-leaf tree), rows
+    ``n_h:`` the Byzantine ones, which deliver every step when
+    ``attacked``.
+
+    Returns:
+      ``(state, metrics)``: the state carrying the updated bus, its
+      ``step`` advanced when the rule is stateless (a stateful rule
+      advances it itself), and the ``async_extras`` of this step.
+    """
+    t = state.step
+    n = jax.tree_util.tree_leaves(rows)[0].shape[0]
+    tau = resolve_tau(spec.async_tau, n)
+    deliver = delivery_mask(t, state.bus.versions, tau,
+                            schedule=spec.async_schedule, seed=spec.seed)
+    if attacked:
+        # Byzantine workers control their own arrival: deliver every
+        # step, stamping a fresh version on adversarial content
+        deliver = deliver | (jnp.arange(n) >= n_h)
+    bus = update_bus(state.bus, rows, t, deliver)
+    state = state._replace(bus=bus)
+    if not spec.rule().stateful:
+        state = state._replace(step=t + 1)
+    return state, async_extras(t - bus.versions,
+                               staleness_excess(bus, t, tau), deliver)
+
+
 def make_async_train_step(cfg, spec: AggSpec, optimizer: Optimizer,
                           impl: str = "auto", mesh=None) -> Callable:
     """Build the jit-able asynchronous sharded Byzantine train step.
 
-    The step always has the stateful signature ``step(params, opt_state,
-    batch, agg_state) -> (params, opt_state, metrics, agg_state)`` —
-    the carried ``AggState`` holds the :class:`GradientBus` (plus the
-    rule's own buffers when ``spec.gar`` is stateful); size it with
-    ``init_async_state``.  ``batch`` has the synchronous layout
-    (``{"tokens", "labels"[, "extra"]}`` with a leading worker axis).
-
-    Per step: all n workers compute fresh gradients against the current
-    parameters (vmap — the simulation pays the sync compute so that
+    The synchronous step's body (``repro.dist.train.make_step_body``;
+    the arguments are ``make_train_step``'s) with :func:`bus_stage`
+    between injection and aggregation, under the scope ``train/bus``.
+    All n workers compute fresh gradients at the current parameters, so
     every *delivered* gradient is genuinely evaluated at the parameters
-    of its version step); under an attack the last f rows are rewritten
-    in-graph (the delay-exploiting ``stale_replay`` / ``slow_drift``
-    additionally read their previous slots); the delay schedule decides
-    who delivers (Byzantine rows always do); the bus absorbs the
-    deliveries; the registry rule aggregates the slot stack.
-
-    With ``spec.async_tau = 0`` and the same spec this reproduces
-    ``repro.dist.train.make_train_step`` bitwise on identical inputs.
-
-    Args:
-      cfg: the ``ModelConfig`` (drives the per-worker forward/backward).
-      spec: unified protocol spec; reads ``async_tau`` /
-        ``async_schedule`` on top of the synchronous fields.
-      optimizer: the ``repro.optim`` optimizer applied to the aggregate.
-      impl: attention implementation forwarded to the model.
-      mesh: optional device mesh, consulted only by the Pallas distance
-        backend (as in the synchronous step).
+    of its version step; the delay attacks read their previous slots;
+    the rule aggregates the slot stack; the metrics add
+    ``async_extras`` (``staleness_excess`` among them).  With
+    ``spec.async_tau = 0`` this reproduces ``make_train_step`` bitwise.
 
     Returns:
-      The jit-able 4-ary step function.
+      ``step(params, opt_state, batch, agg_state) -> (params, opt_state,
+      metrics, agg_state)``, always stateful: the carried ``AggState``
+      holds the :class:`GradientBus` (and the rule's own buffers); size
+      it with ``init_async_state``.
     """
-    loss_fn = make_loss_fn(cfg, impl)
-    vg = jax.value_and_grad(loss_fn)
-    rule = spec.rule()
-    stateful = rule.stateful
-    reputed = "reputation" in rule.state_fields
-
-    def step(params, opt_state, batch, agg_state):
-        tokens, labels = batch["tokens"], batch["labels"]
-        extra = batch.get("extra")
-        n = tokens.shape[0]
-        spec.validate(n, distributed=True)
-        f = spec.f
-        n_h = n - f
-        tau = resolve_tau(spec.async_tau, n)
-        t = agg_state.step
-
-        if extra is None:
-            losses, grads = jax.vmap(
-                lambda tk, l: vg(params, tk, l))(tokens, labels)
-        else:
-            losses, grads = jax.vmap(
-                lambda tk, l, e: vg(params, tk, l, e))(tokens, labels,
-                                                       extra)
-
-        attacked = spec.attack != "none" and f > 0
-        if attacked:
-            key = jax.random.fold_in(jax.random.PRNGKey(spec.seed),
-                                     opt_state["step"])
-            akw = dict(spec.attack_kwargs)
-            akw.setdefault("gar_name", spec.gar)
-            if spec.attack in ("stale_replay", "slow_drift"):
-                akw.setdefault("prev", jax.tree_util.tree_map(
-                    lambda l: l[n_h:], agg_state.bus.grads))
-            grads = inject_byzantine(grads, f, spec.attack, key=key,
-                                     step=t, **akw)
-
-        deliver = delivery_mask(t, agg_state.bus.versions, tau,
-                                schedule=spec.async_schedule,
-                                seed=spec.seed)
-        if attacked:
-            # Byzantine workers control their own arrival: deliver every
-            # step, stamping a fresh version on adversarial content
-            deliver = deliver | (jnp.arange(n) >= n_h)
-        bus = update_bus(agg_state.bus, grads, t, deliver)
-        state_in = agg_state._replace(bus=bus)
-
-        out = distributed_aggregate(
-            bus.grads, spec.f_declared, spec.effective_gar,
-            agg_dtype=spec.agg_dtype,
-            distance_backend=spec.distance_backend, mesh=mesh,
-            state=state_in if stateful else None,
-            history_window=spec.history_window,
-            rep_lr=spec.rep_lr, rep_decay=spec.rep_decay)
-        if stateful:
-            agg, res, new_state = out
-        else:
-            agg, res = out
-            new_state = state_in._replace(step=t + 1)
-
-        step_scale = jnp.ones((), jnp.float32)
-        if reputed:
-            from repro.agg.reputation import (
-                DEFAULT_REP_DECAY, DEFAULT_REP_LR, step_size_multiplier,
-                tree_reputation_scores, update_reputation)
-            if spec.aux_batch is not None:
-                # score the *slot* stack (what was aggregated) against
-                # the clean auxiliary gradient — ByGARS proper
-                aux = tuple(spec.aux_batch)
-                _, clean = vg(params, *aux)
-                scores = tree_reputation_scores(
-                    jax.tree_util.tree_leaves(bus.grads),
-                    jax.tree_util.tree_leaves(clean))
-                lr = (DEFAULT_REP_LR if spec.rep_lr is None
-                      else spec.rep_lr)
-                decay = (DEFAULT_REP_DECAY if spec.rep_decay is None
-                         else spec.rep_decay)
-                new_state = new_state._replace(
-                    reputation=update_reputation(
-                        agg_state.reputation, scores, lr, decay))
-            if spec.rep_lr:
-                # the staleness-adaptive step-size tail (Alistarh et
-                # al.): carried trust shrinks the applied update
-                step_scale = step_size_multiplier(new_state)
-                agg = jax.tree_util.tree_map(
-                    lambda a: (a.astype(jnp.float32)
-                               * step_scale).astype(a.dtype), agg)
-        new_params, new_opt = optimizer.update(agg, opt_state, params)
-
-        honest_mean = jax.tree_util.tree_map(
-            lambda g: jnp.mean(g[:n_h].astype(jnp.float32), axis=0),
-            bus.grads)
-        dev = jax.tree_util.tree_map(
-            lambda a, m: a.astype(jnp.float32) - m, agg, honest_mean)
-        staleness = t - bus.versions
-        metrics = core_metrics(
-            loss=jnp.mean(losses[:n_h]),
-            grad_norm=global_norm(agg),
-            agg_dev=global_norm(dev),
-            byz_weight=selection_weight(res.selected, n_h),
-            step_scale=step_scale if reputed else None)
-        metrics.update(async_extras(staleness,
-                                    staleness_excess(bus, t, tau),
-                                    deliver))
-        return new_params, new_opt, metrics, new_state
-
-    return step
+    return make_step_body(cfg, spec, optimizer, impl, mesh, bus=bus_stage)

@@ -14,19 +14,22 @@ all-reduce.
 The single-host flat-matrix reference lives in ``repro.training.trainer``.
 The asynchronous variant of this step — the same protocol without the
 per-step barrier, aggregating a ``GradientBus`` of versioned per-worker
-slots under bounded staleness — lives in ``repro.dist.async_train``
-(``make_async_train_step`` reuses ``make_loss_fn`` and reproduces this
-step bitwise at ``async_tau = 0``).
+slots under bounded staleness — is this step's body,
+``make_step_body``, with the bus stage of ``repro.dist.async_train``
+between injection and aggregation (bitwise this step at
+``async_tau = 0``).
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.agg.reputation import reputation_tail, tree_reputation_scores
 from repro.agg.specs import AggSpec
 from repro.agg.state import init_state
+from repro.core.attacks import DELAY_ATTACKS
 from repro.dist.robust import distributed_aggregate, inject_byzantine
 from repro.models.config import ModelConfig
 from repro.models.transformer import forward_with_loads
@@ -35,15 +38,8 @@ from repro.obs.schema import (core_metrics, global_norm, moe_metrics,
 from repro.obs.trace import named_span
 from repro.optim import Optimizer
 
-__all__ = ["DistByzantineSpec", "init_agg_state", "make_loss_fn",
+__all__ = ["init_agg_state", "make_loss_fn", "make_step_body",
            "make_train_step"]
-
-#: deprecation alias — the sharded spec is now the unified
-#: ``repro.agg.AggSpec`` (same fields plus the single-host ones);
-#: ``spec.validate(n_workers)`` keeps its historic trace-time call form
-#: (the step builders additionally pass ``distributed=True`` to demand a
-#: tree implementation — no longer inferred from the explicit count).
-DistByzantineSpec = AggSpec
 
 
 def init_agg_state(spec: AggSpec, params, n_workers: int):
@@ -89,12 +85,7 @@ def make_loss_fn(cfg: ModelConfig, impl: str = "auto",
     return loss_fn
 
 
-# per-leaf fp32 norm accumulation now lives in the shared metrics
-# schema; the historic private name stays for the async step's import
-_global_norm = global_norm
-
-
-def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
+def make_train_step(cfg: ModelConfig, spec: AggSpec,
                     optimizer: Optimizer, impl: str = "auto",
                     mesh=None) -> Callable:
     """Build the jit-able sharded Byzantine train step.
@@ -106,9 +97,8 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
     agg_state) -> (params, opt_state, metrics, agg_state)`` with the
     ``AggState`` carried by the caller (see ``init_agg_state``) —
     stateless runs pay nothing.  ``reputation-*`` runs additionally
-    honor ``spec.aux_batch`` (clean-batch ByGARS scoring overrides the
-    agreement update) and a set ``spec.rep_lr`` (the aggregate is scaled
-    by ``step_size_multiplier`` before the optimizer — reported as
+    honor ``spec.aux_batch`` and ``spec.rep_lr`` through
+    ``repro.agg.reputation.reputation_tail`` (the scale is reported as
     ``metrics["step_scale"]``).
 
     batch: ``{"tokens", "labels"[, "extra"]}`` with a leading worker axis
@@ -121,6 +111,32 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
     ``shard_map`` layout of the distance pass); the XLA backend keeps the
     step mesh-agnostic exactly as before — sharding enters via the
     input/output shardings the caller jits with.
+    """
+    run_step = make_step_body(cfg, spec, optimizer, impl, mesh)
+    if spec.rule().stateful:
+        return run_step
+
+    def step(params, opt_state, batch):
+        return run_step(params, opt_state, batch, None)[:3]
+
+    return step
+
+
+def make_step_body(cfg: ModelConfig, spec: AggSpec, optimizer: Optimizer,
+                   impl: str = "auto", mesh=None,
+                   bus: Optional[Callable] = None) -> Callable:
+    """The one body of the sharded train steps, always 4-ary:
+    ``step(params, opt_state, batch, agg_state) -> (params, opt_state,
+    metrics, agg_state)``.
+
+    Its stages, each under a scope: per-worker gradients
+    (``train/grads``), injection (``train/inject``), the aggregation
+    (``agg/...``), the reputation tail and the optimizer
+    (``train/optimizer``), the diagnostics (``train/diagnostics``).
+    ``bus`` is ``None`` for the synchronous step; the asynchronous one
+    passes ``repro.dist.async_train.bus_stage`` (passed in: that module
+    imports this one), run under ``train/bus`` before the aggregation,
+    and carries the bus in ``agg_state``.
     """
     vgl = jax.value_and_grad(make_loss_fn(cfg, impl, with_loads=True),
                              has_aux=True)
@@ -153,54 +169,46 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
                     lambda t, l, e: vgl(params, t, l, e), tokens, labels,
                     extra)
 
-        if spec.attack != "none" and f > 0:
+        attacked = spec.attack != "none" and f > 0
+        if attacked:
             with named_span("train/inject"):
                 key = jax.random.fold_in(jax.random.PRNGKey(spec.seed),
                                          opt_state["step"])
                 akw = dict(spec.attack_kwargs)
                 akw.setdefault("gar_name", spec.gar)
+                # the asynchronous runtime's attacks count bus steps
+                t = opt_state["step"] if bus is None else agg_state.step
+                if bus is not None and spec.attack in DELAY_ATTACKS:
+                    akw.setdefault("prev", jax.tree_util.tree_map(
+                        lambda l: l[n_h:], agg_state.bus.grads))
                 grads = inject_byzantine(grads, f, spec.attack, key=key,
-                                         step=opt_state["step"], **akw)
+                                         step=t, **akw)
+
+        if bus is not None:
+            with named_span("train/bus"):
+                agg_state, bus_metrics = bus(spec, agg_state, grads, n_h,
+                                             attacked)
+            grads = agg_state.bus.grads
 
         out = distributed_aggregate(
             grads, spec.f_declared, spec.effective_gar,
             agg_dtype=spec.agg_dtype,
             distance_backend=spec.distance_backend, mesh=mesh,
-            state=agg_state, history_window=spec.history_window,
+            state=agg_state if stateful else None,
+            history_window=spec.history_window,
             rep_lr=spec.rep_lr, rep_decay=spec.rep_decay)
         agg, res = out[0], out[1]
-        new_agg_state = out[2] if stateful else None
+        new_agg_state = out[2] if stateful else agg_state
 
         with named_span("train/optimizer"):
-            step_scale = jnp.ones((), jnp.float32)
+            step_scale = None
             if reputed:
-                from repro.agg.reputation import (
-                    DEFAULT_REP_DECAY, DEFAULT_REP_LR, step_size_multiplier,
-                    tree_reputation_scores, update_reputation)
-                if spec.aux_batch is not None:
-                    # ByGARS proper: score raw submissions against the clean
-                    # auxiliary gradient, overriding the rule's own
-                    # agreement-with-the-aggregate update — the only signal
-                    # a colluding majority cannot vote on
-                    aux = tuple(spec.aux_batch)
-                    _, clean = vgl(params, *aux)
-                    scores = tree_reputation_scores(
+                new_agg_state, agg, step_scale = reputation_tail(
+                    spec, agg_state.reputation, new_agg_state, agg,
+                    lambda: tree_reputation_scores(
                         jax.tree_util.tree_leaves(grads),
-                        jax.tree_util.tree_leaves(clean))
-                    lr = (DEFAULT_REP_LR if spec.rep_lr is None
-                          else spec.rep_lr)
-                    decay = (DEFAULT_REP_DECAY if spec.rep_decay is None
-                             else spec.rep_decay)
-                    new_agg_state = new_agg_state._replace(
-                        reputation=update_reputation(
-                            agg_state.reputation, scores, lr, decay))
-                if spec.rep_lr:
-                    # staleness-adaptive step size (Alistarh et al.): the
-                    # same carried trust scales the update magnitude
-                    step_scale = step_size_multiplier(new_agg_state)
-                    agg = jax.tree_util.tree_map(
-                        lambda a: (a.astype(jnp.float32)
-                                   * step_scale).astype(a.dtype), agg)
+                        jax.tree_util.tree_leaves(
+                            vgl(params, *spec.aux_batch)[1])))
             new_params, new_state = optimizer.update(agg, opt_state, params)
 
         with named_span("train/diagnostics"):
@@ -213,15 +221,11 @@ def make_train_step(cfg: ModelConfig, spec: DistByzantineSpec,
                 grad_norm=global_norm(agg),
                 agg_dev=global_norm(dev),
                 byz_weight=selection_weight(res.selected, n_h),
-                step_scale=step_scale if reputed else None)
+                step_scale=step_scale)
             if loads.size:
                 metrics.update(moe_metrics(loads))
+        if bus is not None:
+            metrics.update(bus_metrics)
         return new_params, new_state, metrics, new_agg_state
 
-    if stateful:
-        return run_step
-
-    def step(params, opt_state, batch):
-        return run_step(params, opt_state, batch, None)[:3]
-
-    return step
+    return run_step

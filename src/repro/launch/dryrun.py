@@ -148,8 +148,8 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     from repro.dist.serve_robust import (init_ensemble_state,
                                          make_robust_serve_step,
                                          make_robust_verify_step)
-    from repro.dist.train import (DistByzantineSpec, init_agg_state,
-                                  make_train_step)
+    from repro.agg import AggSpec
+    from repro.dist.train import init_agg_state, make_train_step
     from repro.launch import specs as S
     from repro.models.config import INPUT_SHAPES
     from repro.optim import get_optimizer
@@ -205,13 +205,13 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             # initialized abstractly so nothing is materialized
             opt = get_optimizer(optimizer_name, 1e-3)
             opt_state, opt_sh = S.opt_specs(params, opt, mesh)
-            spec = DistByzantineSpec(f=3, gar=gar, attack=attack,
-                                     agg_dtype=agg_dtype,
-                                     distance_backend=distance_backend,
-                                     rep_lr=rep_lr,
-                                     async_tau=async_tau,
-                                     async_schedule=async_schedule,
-                                     telemetry=telemetry)
+            spec = AggSpec(f=3, gar=gar, attack=attack,
+                           agg_dtype=agg_dtype,
+                           distance_backend=distance_backend,
+                           rep_lr=rep_lr,
+                           async_tau=async_tau,
+                           async_schedule=async_schedule,
+                           telemetry=telemetry)
             record.update(async_tau=async_tau,
                           async_schedule=async_schedule)
             if rep_lr is not None:
@@ -227,11 +227,11 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         elif shape.kind == "train":
             opt = get_optimizer(optimizer_name, 1e-3)
             opt_state, opt_sh = S.opt_specs(params, opt, mesh)
-            spec = DistByzantineSpec(f=3, gar=gar, attack=attack,
-                                     agg_dtype=agg_dtype,
-                                     distance_backend=distance_backend,
-                                     rep_lr=rep_lr,
-                                     telemetry=telemetry)
+            spec = AggSpec(f=3, gar=gar, attack=attack,
+                           agg_dtype=agg_dtype,
+                           distance_backend=distance_backend,
+                           rep_lr=rep_lr,
+                           telemetry=telemetry)
             if rep_lr is not None:
                 record.update(rep_lr=rep_lr)
             step = make_train_step(cfg, spec, opt, impl=impl, mesh=mesh)
@@ -261,11 +261,11 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             # the replica axis on ``data``, per-token logits aggregation
             # through the registry (repro.dist.serve_robust)
             n_rep = serve_replicas or quorum(serve_gar, serve_f)
-            sspec = DistByzantineSpec(f=serve_f, gar=serve_gar,
-                                      agg_dtype=agg_dtype,
-                                      distance_backend=distance_backend,
-                                      speculative_k=serve_speculative_k,
-                                      telemetry=telemetry)
+            sspec = AggSpec(f=serve_f, gar=serve_gar,
+                            agg_dtype=agg_dtype,
+                            distance_backend=distance_backend,
+                            speculative_k=serve_speculative_k,
+                            telemetry=telemetry)
             record.update(serve_gar=serve_gar, serve_f=serve_f,
                           serve_replicas=n_rep,
                           serve_speculative_k=serve_speculative_k)

@@ -1,9 +1,9 @@
-from repro.training.trainer import (AsyncByzantineTrainer, ByzantineSpec,
-                                    ByzantineTrainer, init_flat_agg_state,
+from repro.training.trainer import (AsyncByzantineTrainer, ByzantineTrainer,
+                                    init_flat_agg_state,
                                     init_flat_async_state,
                                     make_async_byzantine_step,
                                     make_byzantine_step)
 
-__all__ = ["AsyncByzantineTrainer", "ByzantineSpec", "ByzantineTrainer",
+__all__ = ["AsyncByzantineTrainer", "ByzantineTrainer",
            "init_flat_agg_state", "init_flat_async_state",
            "make_async_byzantine_step", "make_byzantine_step"]

@@ -13,12 +13,14 @@ step and the trainer loop, while stateless rules keep the historic
 signatures untouched.
 
 The *asynchronous* flat reference (``make_async_byzantine_step`` /
-``AsyncByzantineTrainer``) drops the per-step barrier: submissions live
-in a ``GradientBus`` (``repro.dist.async_train``) of per-worker
-versioned slots, an in-graph delay schedule decides who delivers, and
-the rule — typically a staleness-weighted ``stale-<base>`` — aggregates
-the slot stack.  With ``spec.async_tau = 0`` the async step reproduces
-the synchronous one exactly (see docs/async-runtime.md).
+``AsyncByzantineTrainer``) drops the per-step barrier: it is the
+synchronous step's body with one more stage,
+``repro.dist.async_train.bus_stage``, between injection and aggregation.
+Submissions live in a ``GradientBus`` of per-worker versioned slots, an
+in-graph delay schedule decides who delivers, and the rule — typically
+a staleness-weighted ``stale-<base>`` — aggregates the slot stack.  With
+``spec.async_tau = 0`` the async step reproduces the synchronous one
+exactly (see docs/async-runtime.md).
 
 The mesh-sharded production variants live in ``repro.dist.train`` /
 ``repro.dist.async_train`` — this module is the semantics reference
@@ -34,21 +36,13 @@ import jax.numpy as jnp
 
 from repro.agg.specs import AggSpec
 from repro.agg.state import AggState, init_state
-from repro.agg.reputation import (DEFAULT_REP_DECAY, DEFAULT_REP_LR,
-                                  reputation_scores, step_size_multiplier,
-                                  update_reputation)
+from repro.agg.reputation import reputation_scores, reputation_tail
 from repro.core import attacks as attacks_lib
 from repro.core import pytree as pt
-from repro.dist.async_train import (delivery_mask, init_bus, resolve_tau,
-                                    staleness_excess, update_bus)
+from repro.dist.async_train import bus_stage, init_bus
 from repro.obs.buffer import drain
-from repro.obs.schema import async_extras, core_metrics, selection_weight
+from repro.obs.schema import core_metrics, selection_weight
 from repro.optim import Optimizer
-
-#: deprecation alias — the single-host spec is now the unified
-#: ``repro.agg.AggSpec``; ``spec.validate()`` keeps reading
-#: ``spec.n_workers`` as before.
-ByzantineSpec = AggSpec
 
 
 def init_flat_agg_state(spec: AggSpec, params,
@@ -90,7 +84,7 @@ def _flat_grad(grad_fn: Callable, params, batch) -> jnp.ndarray:
 
 
 def make_byzantine_step(loss_fn: Callable, optimizer: Optimizer,
-                        spec: ByzantineSpec,
+                        spec: AggSpec,
                         attack_on: bool = True) -> Callable:
     """Build a jit-able training step.
 
@@ -99,6 +93,26 @@ def make_byzantine_step(loss_fn: Callable, optimizer: Optimizer,
     Returns step(params, opt_state, x, y, key) ->
         (params, opt_state, metrics dict); a stateful GAR appends an
     ``agg_state`` argument and return slot (carried by the caller).
+    """
+    run_step = _step_body(loss_fn, optimizer, spec, attack_on)
+    if spec.rule().stateful:
+        return run_step
+
+    def step(params, opt_state, x, y, key):
+        return run_step(params, opt_state, x, y, key, None)[:3]
+
+    return step
+
+
+def _step_body(loss_fn: Callable, optimizer: Optimizer, spec: AggSpec,
+               attack_on: bool = True,
+               bus: Optional[Callable] = None) -> Callable:
+    """The one body of the flat steps, always ``step(params, opt_state,
+    x, y, key, agg_state) -> (params, opt_state, metrics, agg_state)``.
+
+    ``bus`` is ``None`` for the synchronous step; the asynchronous one
+    passes ``repro.dist.async_train.bus_stage``, run between injection
+    and aggregation over the flat ``(n, d)`` matrix.
     """
     spec.validate()
     rule = spec.rule()
@@ -110,64 +124,55 @@ def make_byzantine_step(loss_fn: Callable, optimizer: Optimizer,
         grad_fn = jax.grad(loss_fn)
         worker_grads = jax.vmap(lambda xi, yi: grad_fn(params, xi, yi))(x, y)
         flat, ctx = pt.stack_flatten(worker_grads)      # (n_honest, d)
+        n_h = spec.n_honest
 
-        if attack is not None and spec.f > 0:
+        attacked = attack is not None and spec.f > 0
+        if attacked:
             kw = dict(akw)
             if attack in (attacks_lib.omniscient_lp,
                           attacks_lib.omniscient_linf,
                           attacks_lib.reputation_burn):
                 kw.setdefault("step", opt_state["step"])
+            if bus is not None and spec.attack in attacks_lib.DELAY_ATTACKS:
+                kw.setdefault("prev", agg_state.bus.grads[n_h:])
+                kw.setdefault("step", agg_state.step)
             byz = attack(flat, spec.f, key, **kw)
             full = jnp.concatenate([flat, byz], axis=0)
         else:
             full = flat
-        n_eff = full.shape[0]
 
-        rep_prev = agg_state.reputation if reputed else None
+        if bus is not None:
+            agg_state, bus_metrics = bus(spec, agg_state, full, n_h,
+                                         attacked)
+            full = agg_state.bus.grads
+
         if rule.stateful:
-            res, agg_state = rule.dense_fn(full, spec.f_declared, agg_state)
+            res, new_state = rule.dense_fn(full, spec.f_declared, agg_state)
         else:
             res = rule.dense_fn(full, spec.f_declared)
+            new_state = agg_state
         grad_out = res.gradient
-        step_scale = jnp.ones((), jnp.float32)
+        step_scale = None
         if reputed:
-            if spec.aux_batch is not None:
-                # ByGARS proper: re-score against the clean auxiliary
-                # gradient (the aggregate itself can be owned by a
-                # colluding majority), overriding the rule's own
-                # agreement update of this step
-                target = _flat_grad(grad_fn, params, spec.aux_batch)
-                lr = (DEFAULT_REP_LR if spec.rep_lr is None
-                      else spec.rep_lr)
-                decay = (DEFAULT_REP_DECAY if spec.rep_decay is None
-                         else spec.rep_decay)
-                agg_state = agg_state._replace(
-                    reputation=update_reputation(
-                        rep_prev, reputation_scores(full, target),
-                        lr, decay))
-            if spec.rep_lr:
-                # staleness-adaptive step size (Alistarh et al.)
-                step_scale = step_size_multiplier(agg_state)
-                grad_out = grad_out * step_scale
+            new_state, grad_out, step_scale = reputation_tail(
+                spec, agg_state.reputation, new_state, grad_out,
+                lambda: reputation_scores(
+                    full, _flat_grad(grad_fn, params, spec.aux_batch)))
         agg = pt.unflatten(grad_out, ctx)
-        new_params, new_state = optimizer.update(agg, opt_state, params)
+        new_params, new_opt = optimizer.update(agg, opt_state, params)
 
-        honest_mean = jnp.mean(flat, axis=0)
+        honest_mean = jnp.mean(full[:n_h], axis=0)
         metrics = core_metrics(
             loss=loss_fn(params, x[0], y[0]),
-            byz_weight=selection_weight(res.selected, spec.n_honest),
+            byz_weight=selection_weight(res.selected, n_h),
             agg_dev=jnp.linalg.norm(res.gradient - honest_mean),
             grad_norm=jnp.linalg.norm(res.gradient),
-            step_scale=step_scale if reputed else None)
-        return new_params, new_state, metrics, agg_state
+            step_scale=step_scale)
+        if bus is not None:
+            metrics.update(bus_metrics)
+        return new_params, new_opt, metrics, new_state
 
-    if rule.stateful:
-        return run_step
-
-    def step(params, opt_state, x, y, key):
-        return run_step(params, opt_state, x, y, key, None)[:3]
-
-    return step
+    return run_step
 
 
 class ByzantineTrainer:
@@ -184,21 +189,24 @@ class ByzantineTrainer:
     """
 
     def __init__(self, loss_fn, params, optimizer: Optimizer,
-                 spec: ByzantineSpec, seed: int = 0):
+                 spec: AggSpec, seed: int = 0):
         self.spec = spec
         self.params = params
         self.optimizer = optimizer
         self.opt_state = optimizer.init(params)
         self._rule = spec.rule()
-        self._stateful = self._rule.stateful
         self._attack_mode = spec.f > 0 and spec.attack != "none"
-        self.agg_state = init_flat_agg_state(spec, params)
-        self._step_attacked = jax.jit(
-            make_byzantine_step(loss_fn, optimizer, spec, attack_on=True))
-        self._step_clean = jax.jit(
-            make_byzantine_step(loss_fn, optimizer, spec, attack_on=False))
+        self._stateful, self.agg_state, self._steps = self._program(loss_fn)
         self.key = jax.random.PRNGKey(seed)
         self.history: list = []
+
+    def _program(self, loss_fn):
+        """``(stateful, initial agg_state, {attack_on: jitted step})``."""
+        steps = {on: jax.jit(make_byzantine_step(loss_fn, self.optimizer,
+                                                 self.spec, attack_on=on))
+                 for on in (True, False)}
+        return (self._rule.stateful,
+                init_flat_agg_state(self.spec, self.params), steps)
 
     def run(self, batcher, n_steps: int, attack_until: Optional[int] = None,
             eval_fn: Optional[Callable] = None, eval_every: int = 0,
@@ -209,7 +217,7 @@ class ByzantineTrainer:
             attacked = (attack_until is None) or (t < attack_until)
             use_attack = (attacked and self.spec.f > 0
                           and self.spec.attack != "none")
-            fn = self._step_attacked if use_attack else self._step_clean
+            fn = self._steps[use_attack]
             if self._stateful and use_attack != self._attack_mode:
                 self._attack_mode = use_attack
                 # per-worker buffers are row-count-dependent: the
@@ -297,13 +305,9 @@ def make_async_byzantine_step(loss_fn: Callable, optimizer: Optimizer,
     """Build the jit-able asynchronous flat training step.
 
     The single-host reference of ``repro.dist.async_train
-    .make_async_train_step``: same ``GradientBus`` protocol over the
-    flat ``(n, d)`` matrix.  All workers compute fresh gradients, the
-    last f are rewritten by the configured attack (the delay-exploiting
-    ``stale_replay`` / ``slow_drift`` read their previous bus rows), the
-    delay schedule (``spec.async_tau`` / ``spec.async_schedule``)
-    decides which honest workers deliver — Byzantine rows always do —
-    and the registry rule aggregates the slot stack.
+    .make_async_train_step``: :func:`make_byzantine_step`'s body with
+    ``repro.dist.async_train.bus_stage`` between injection and
+    aggregation, over the flat ``(n, d)`` matrix.
 
     Unlike ``make_byzantine_step`` there is no ``attack_on`` variant:
     the bus row count is baked into the carried state, so the lock-free
@@ -324,93 +328,13 @@ def make_async_byzantine_step(loss_fn: Callable, optimizer: Optimizer,
       ``spec.async_tau = 0`` the step reproduces
       ``make_byzantine_step`` bitwise on identical inputs.
     """
-    spec.validate()
-    rule = spec.rule()
-    reputed = "reputation" in rule.state_fields
-    attack = attacks_lib.get_attack(spec.attack)
-    akw = dict(spec.attack_kwargs)
-    delay_attacks = (attacks_lib.stale_replay, attacks_lib.slow_drift)
-
-    def step(params, opt_state, x, y, key, agg_state):
-        grad_fn = jax.grad(loss_fn)
-        worker_grads = jax.vmap(lambda xi, yi: grad_fn(params, xi, yi))(x, y)
-        flat, ctx = pt.stack_flatten(worker_grads)      # (n_honest, d)
-        t = agg_state.step
-        n_h = spec.n_honest
-
-        attacked = attack is not None and spec.f > 0
-        if attacked:
-            kw = dict(akw)
-            if attack in (attacks_lib.omniscient_lp,
-                          attacks_lib.omniscient_linf,
-                          attacks_lib.reputation_burn):
-                kw.setdefault("step", opt_state["step"])
-            if attack in delay_attacks:
-                kw.setdefault("prev", agg_state.bus.grads[n_h:])
-                kw.setdefault("step", t)
-            byz = attack(flat, spec.f, key, **kw)
-            full = jnp.concatenate([flat, byz], axis=0)
-        else:
-            full = flat
-        n_eff = full.shape[0]
-
-        tau = resolve_tau(spec.async_tau, n_eff)
-        deliver = delivery_mask(t, agg_state.bus.versions, tau,
-                                schedule=spec.async_schedule,
-                                seed=spec.seed)
-        if attacked:
-            deliver = deliver | (jnp.arange(n_eff) >= n_h)
-        bus = update_bus(agg_state.bus, full, t, deliver)
-        state_in = agg_state._replace(bus=bus)
-
-        rep_prev = agg_state.reputation if reputed else None
-        if rule.stateful:
-            res, new_state = rule.dense_fn(bus.grads, spec.f_declared,
-                                           state_in)
-        else:
-            res = rule.dense_fn(bus.grads, spec.f_declared)
-            new_state = state_in._replace(step=t + 1)
-        grad_out = res.gradient
-        step_scale = jnp.ones((), jnp.float32)
-        if reputed:
-            if spec.aux_batch is not None:
-                # score the slot stack (what was aggregated) against the
-                # clean auxiliary gradient — ByGARS proper
-                target = _flat_grad(grad_fn, params, spec.aux_batch)
-                lr = (DEFAULT_REP_LR if spec.rep_lr is None
-                      else spec.rep_lr)
-                decay = (DEFAULT_REP_DECAY if spec.rep_decay is None
-                         else spec.rep_decay)
-                new_state = new_state._replace(
-                    reputation=update_reputation(
-                        rep_prev, reputation_scores(bus.grads, target),
-                        lr, decay))
-            if spec.rep_lr:
-                step_scale = step_size_multiplier(new_state)
-                grad_out = grad_out * step_scale
-        agg = pt.unflatten(grad_out, ctx)
-        new_params, new_opt = optimizer.update(agg, opt_state, params)
-
-        honest_mean = jnp.mean(bus.grads[:n_h], axis=0)
-        staleness = t - bus.versions
-        metrics = core_metrics(
-            loss=loss_fn(params, x[0], y[0]),
-            byz_weight=selection_weight(res.selected, n_h),
-            agg_dev=jnp.linalg.norm(res.gradient - honest_mean),
-            grad_norm=jnp.linalg.norm(res.gradient),
-            step_scale=step_scale if reputed else None)
-        metrics.update(async_extras(staleness,
-                                    staleness_excess(bus, t, tau),
-                                    deliver))
-        return new_params, new_opt, metrics, new_state
-
-    return step
+    return _step_body(loss_fn, optimizer, spec, bus=bus_stage)
 
 
-class AsyncByzantineTrainer:
+class AsyncByzantineTrainer(ByzantineTrainer):
     """Convenience loop for the asynchronous runtime (flat reference).
 
-    Mirrors :class:`ByzantineTrainer` but drives
+    :class:`ByzantineTrainer`'s loop driving
     :func:`make_async_byzantine_step`: the trainer owns the carried
     ``AggState`` — whose ``bus`` holds every worker's versioned slot —
     zero-initialized at construction and threaded across ``run`` calls.
@@ -419,56 +343,15 @@ class AsyncByzantineTrainer:
     lock-free protocol where the committee never re-synchronizes.
     """
 
-    def __init__(self, loss_fn, params, optimizer: Optimizer,
-                 spec: AggSpec, seed: int = 0):
-        self.spec = spec
-        self.params = params
-        self.optimizer = optimizer
-        self.opt_state = optimizer.init(params)
-        self.agg_state = init_flat_async_state(spec, params)
-        self._step = jax.jit(
-            make_async_byzantine_step(loss_fn, optimizer, spec))
-        self.key = jax.random.PRNGKey(seed)
-        self.history: list = []
+    def _program(self, loss_fn):
+        step = jax.jit(make_async_byzantine_step(loss_fn, self.optimizer,
+                                                 self.spec))
+        return (True, init_flat_async_state(self.spec, self.params),
+                {True: step, False: step})
 
     def run(self, batcher, n_steps: int,
             eval_fn: Optional[Callable] = None, eval_every: int = 0,
             start_step: int = 0):
-        """Drive the jitted async step for ``n_steps`` (see
-        :meth:`ByzantineTrainer.run` for the loop contract).
-
-        Args:
-          batcher: per-honest-worker batch source (``batcher.batch(t)``).
-          n_steps: number of async steps to run.
-          eval_fn: optional ``params -> accuracy`` probe.
-          eval_every: evaluation period (0 = never).
-          start_step: first step index (continuation support).
-
-        Returns:
-          The accumulated metrics history (list of per-step dicts).
-        """
-        for t in range(start_step, start_step + n_steps):
-            x, y = batcher.batch(t)
-            self.key, sub = jax.random.split(self.key)
-            (self.params, self.opt_state, m,
-             self.agg_state) = self._step(self.params, self.opt_state,
-                                          jnp.asarray(x), jnp.asarray(y),
-                                          sub, self.agg_state)
-            rec = {k: float(v) for k, v in m.items()}
-            rec["step"] = t
-            if eval_fn and eval_every and t % eval_every == 0:
-                rec["eval_acc"] = float(eval_fn(self.params))
-            self.history.append(rec)
-        return self.history
-
-    def telemetry(self):
-        """Drain the carried aggregation-forensics ring to host numpy.
-
-        Args:
-          (none) — reads ``self.agg_state.obs``.
-
-        Returns:
-          ``repro.obs.buffer.drain``'s dict; empty when the spec was
-          built without ``telemetry=True``.
-        """
-        return drain(self.agg_state.obs)
+        """:meth:`ByzantineTrainer.run` without ``attack_until``."""
+        return super().run(batcher, n_steps, None, eval_fn, eval_every,
+                           start_step)

@@ -13,8 +13,6 @@ from repro.agg import (AggSpec, AggState, check_quorum, init_state, quorum,
                        resolve_rule, rule_names)
 from repro.core import pytree as pt
 from repro.dist.robust import distributed_aggregate
-from repro.dist.train import DistByzantineSpec
-from repro.training import ByzantineSpec
 
 KEY = jax.random.PRNGKey(7)
 
@@ -71,19 +69,26 @@ class TestResolver:
 
 class TestSpecUnification:
     def test_old_names_are_one_type(self):
-        assert ByzantineSpec is AggSpec
-        assert DistByzantineSpec is AggSpec
+        """The per-path spec names are gone: every path takes AggSpec."""
+        import repro.dist
+        import repro.dist.train
+        import repro.training
+        import repro.training.trainer
+        for mod in (repro.dist, repro.dist.train, repro.training,
+                    repro.training.trainer):
+            assert not hasattr(mod, "DistByzantineSpec"), mod
+            assert not hasattr(mod, "ByzantineSpec"), mod
 
     def test_both_validate_forms_work(self):
-        ByzantineSpec(n_workers=15, f=3, gar="krum").validate()
-        DistByzantineSpec(f=3, gar="krum").validate(15)
+        AggSpec(n_workers=15, f=3, gar="krum").validate()
+        AggSpec(f=3, gar="krum").validate(15)
 
     def test_quorum_messages_agree(self):
         msgs = []
-        for call in (lambda: ByzantineSpec(n_workers=6, f=3,
-                                           gar="krum").validate(),
-                     lambda: DistByzantineSpec(f=3, gar="krum").validate(6),
-                     lambda: check_quorum("krum", 6, 3)):
+        for call in (lambda: AggSpec(n_workers=6, f=3,
+                                     gar="krum").validate(),
+               lambda: AggSpec(f=3, gar="krum").validate(6),
+               lambda: check_quorum("krum", 6, 3)):
             with pytest.raises(ValueError) as e:
                 call()
             msgs.append(str(e.value))
@@ -103,7 +108,7 @@ class TestSpecUnification:
         distances)."""
         AggSpec(n_workers=7, f=1, gar="bulyan-brute").validate()
         with pytest.raises(KeyError, match="distance-only"):
-            DistByzantineSpec(f=1, gar="bulyan-brute").validate(
+            AggSpec(f=1, gar="bulyan-brute").validate(
                 7, distributed=True)
 
     @pytest.mark.parametrize("gar", ["bulyan-brute", "stale-bulyan-brute",
@@ -264,8 +269,8 @@ class TestTrainerIntegration:
             return simple.classification_loss(
                 simple.mnist_mlp_forward(params, x), y, params)
 
-        spec = ByzantineSpec(n_workers=9, f=1, gar="buffered-cwmed",
-                             attack="signflip", history_window=3)
+        spec = AggSpec(n_workers=9, f=1, gar="buffered-cwmed",
+                       attack="signflip", history_window=3)
         tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                               get_optimizer("sgd", 0.1), spec)
         tr.run(ByzantineBatcher("mnist", spec.n_honest, 16), 4)
@@ -285,9 +290,9 @@ class TestTrainerIntegration:
             return simple.classification_loss(
                 simple.mnist_mlp_forward(params, x), y, params)
 
-        spec = ByzantineSpec(n_workers=9, f=1,
-                             gar="centered_clip_momentum",
-                             attack="signflip")
+        spec = AggSpec(n_workers=9, f=1,
+                       gar="centered_clip_momentum",
+                       attack="signflip")
         tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                               get_optimizer("sgd", 0.1), spec)
         tr.run(ByzantineBatcher("mnist", spec.n_honest, 16), 4,
@@ -306,8 +311,8 @@ class TestTrainerIntegration:
         cfg = get_reduced("llama3_2_3b")
         params = init_model(jax.random.PRNGKey(0), cfg)
         opt = get_optimizer("momentum", 1e-2)
-        spec = DistByzantineSpec(f=0, gar="buffered-cwmed",
-                                 history_window=2)
+        spec = AggSpec(f=0, gar="buffered-cwmed",
+                       history_window=2)
         step = jax.jit(make_train_step(cfg, spec, opt))
         n, b, s = 4, 2, 16
         batch = {

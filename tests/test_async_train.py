@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.agg import init_state, quorum, resolve_rule, rule_names
+from repro.agg import AggSpec, init_state, quorum, resolve_rule, rule_names
 from repro.core import pytree as pt
 from repro.data import ByzantineBatcher
 from repro.data.synthetic import mnist_like
@@ -29,8 +29,7 @@ from repro.dist.async_train import (GradientBus, delivery_mask,
 from repro.dist.robust import distributed_aggregate
 from repro.models import simple
 from repro.optim import fading_lr, get_optimizer
-from repro.training import (AsyncByzantineTrainer, ByzantineSpec,
-                            init_flat_async_state,
+from repro.training import (AsyncByzantineTrainer, init_flat_async_state,
                             make_async_byzantine_step, make_byzantine_step)
 
 KEY = jax.random.PRNGKey(11)
@@ -219,11 +218,11 @@ class TestTau0Equivalence:
         f = 3 if attack != "none" else 0
         n_h = 12
         base = gar.replace("stale-", "")
-        spec = ByzantineSpec(
+        spec = AggSpec(
             n_workers=n_h + f, f=f, gar=gar, attack=attack,
             attack_kwargs=(("gar_name", "krum"),) if f else (),
             async_tau=0)
-        sspec = ByzantineSpec(
+        sspec = AggSpec(
             n_workers=n_h + f, f=f, gar=base, attack=attack,
             attack_kwargs=spec.attack_kwargs)
         params = simple.init_mnist_mlp(KEY)
@@ -252,7 +251,7 @@ class TestTau0Equivalence:
         function runs under GSPMD, see tests/test_dist.py) at tau=0
         equals the synchronous make_train_step bitwise."""
         from repro.configs import get_reduced
-        from repro.dist.train import DistByzantineSpec, make_train_step
+        from repro.dist.train import make_train_step
         from repro.models import init_model
 
         cfg = get_reduced("llama3_2_3b")
@@ -263,9 +262,9 @@ class TestTau0Equivalence:
                                               cfg.vocab_size),
                  "labels": jax.random.randint(KEY, (n, b, s), 0,
                                               cfg.vocab_size)}
-        spec = DistByzantineSpec(f=f, gar=gar, attack=attack, async_tau=0)
-        sspec = DistByzantineSpec(f=f, gar=gar.replace("stale-", ""),
-                                  attack=attack)
+        spec = AggSpec(f=f, gar=gar, attack=attack, async_tau=0)
+        sspec = AggSpec(f=f, gar=gar.replace("stale-", ""),
+                        attack=attack)
         sync = jax.jit(make_train_step(cfg, sspec, opt))
         astep = jax.jit(make_async_train_step(cfg, spec, opt))
         p1, o1, m1 = sync(params, opt.init(params), batch)
@@ -284,6 +283,40 @@ class TestTau0Equivalence:
         assert float(m2["delivered"]) == n
         assert int(st2.step) == 1
 
+    def test_dist_step_runs_dropless_experts_bitwise(self):
+        """The benchmark's expert configuration at a small width: dropless
+        held-share experts, whose grouped matmul runs the workers one
+        after another.  At tau=0 the asynchronous sharded step equals
+        make_train_step bitwise on it and reports the expert loads."""
+        from test_mla_moe import _cfg, _params, _tokens, family
+        from repro.dist.train import make_train_step
+
+        cfg = _cfg()
+        mcfg = family.program_config(cfg)
+        params = dict(_params(cfg), tail={})
+        n = 7
+        toks, labs = _tokens(cfg, n=n)
+        batch = {"tokens": toks[:, None], "labels": labs[:, None]}
+        opt = get_optimizer("adamw", 3e-4, weight_decay=0.01)
+        kw = dict(f=1, gar="bulyan-krum", attack="omniscient_linf",
+                  attack_kwargs=(("margin", 3.0),))
+        spec = AggSpec(async_tau=0, **kw)
+        sync = jax.jit(make_train_step(mcfg, AggSpec(**kw), opt))
+        astep = jax.jit(make_async_train_step(mcfg, spec, opt))
+        p1, o1, m1 = sync(params, opt.init(params), batch)
+        st = init_async_state(spec, params, n)
+        p2, o2, m2, st2 = astep(params, opt.init(params), batch, st)
+        for a, bb in zip(jax.tree_util.tree_leaves((p1, o1)),
+                         jax.tree_util.tree_leaves((p2, o2))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(bb))
+        moe = [k for k in m1 if k.startswith("moe_")]
+        assert moe
+        for key in m1:
+            np.testing.assert_array_equal(np.asarray(m1[key]),
+                                          np.asarray(m2[key]), err_msg=key)
+        assert float(m2["delivered"]) == n
+        assert int(st2.step) == 1
+
 
 # ---------------------------------------------------------------------------
 # the delay attacks vs the staleness-aware defenses
@@ -291,10 +324,10 @@ class TestTau0Equivalence:
 
 class TestStaleReplayDefense:
     def _run(self, gar, attack, steps=40):
-        spec = ByzantineSpec(n_workers=39, f=9, gar=gar, attack=attack,
-                             async_tau=3,
-                             attack_kwargs=(("scale", -4.0), ("hold", 4))
-                             if attack == "stale_replay" else ())
+        spec = AggSpec(n_workers=39, f=9, gar=gar, attack=attack,
+                       async_tau=3,
+                       attack_kwargs=(("scale", -4.0), ("hold", 4))
+                       if attack == "stale_replay" else ())
         tr = AsyncByzantineTrainer(
             mnist_loss, simple.init_mnist_mlp(KEY),
             get_optimizer("sgd", fading_lr(1.0, 10000)), spec)
@@ -319,8 +352,8 @@ class TestStaleReplayDefense:
         assert acc > 0.9, acc
 
     def test_clean_async_training_learns(self):
-        spec = ByzantineSpec(n_workers=30, f=0, gar="stale-krum",
-                             attack="none", async_tau=3)
+        spec = AggSpec(n_workers=30, f=0, gar="stale-krum",
+                       attack="none", async_tau=3)
         tr = AsyncByzantineTrainer(
             mnist_loss, simple.init_mnist_mlp(KEY),
             get_optimizer("sgd", fading_lr(1.0, 10000)), spec)
@@ -335,9 +368,9 @@ class TestStaleReplayDefense:
     def test_slow_drift_biases_average(self):
         """The drift integrates: average's deviation from the honest
         mean grows across steps while stale-bulyan's stays flat."""
-        spec = ByzantineSpec(n_workers=39, f=9, gar="average",
-                             attack="slow_drift", async_tau=3,
-                             attack_kwargs=(("eps", 1.0),))
+        spec = AggSpec(n_workers=39, f=9, gar="average",
+                       attack="slow_drift", async_tau=3,
+                       attack_kwargs=(("eps", 1.0),))
         tr = AsyncByzantineTrainer(
             mnist_loss, simple.init_mnist_mlp(KEY),
             get_optimizer("sgd", fading_lr(1.0, 10000)), spec)
@@ -353,14 +386,13 @@ class TestStaleReplayDefense:
 class TestAsyncStatePlumbing:
     def test_init_async_state_composes_with_eval_shape(self):
         from repro.configs import get_reduced
-        from repro.dist.train import DistByzantineSpec
         from repro.models import init_model
 
         cfg = get_reduced("llama3_2_3b")
         params = jax.eval_shape(
             lambda: init_model(jax.random.PRNGKey(0), cfg))
         for gar in ("krum", "stale-bulyan-krum", "stale-buffered-cwmed"):
-            spec = DistByzantineSpec(f=1, gar=gar, async_tau=2)
+            spec = AggSpec(f=1, gar=gar, async_tau=2)
             st = jax.eval_shape(lambda: init_async_state(spec, params, 7))
             assert isinstance(st.bus, GradientBus)
             assert st.bus.versions.shape == (7,)
@@ -369,13 +401,13 @@ class TestAsyncStatePlumbing:
     def test_flat_state_always_carries_bus(self):
         params = simple.init_mnist_mlp(KEY)
         for gar in ("average", "stale-krum"):
-            spec = ByzantineSpec(n_workers=9, f=1, gar=gar,
-                                 attack="signflip", async_tau=1)
+            spec = AggSpec(n_workers=9, f=1, gar=gar,
+                           attack="signflip", async_tau=1)
             st = init_flat_async_state(spec, params)
             assert isinstance(st.bus, GradientBus)
             assert st.bus.grads.shape[0] == 9
-        spec = ByzantineSpec(n_workers=9, f=0, gar="average",
-                             attack="none")
+        spec = AggSpec(n_workers=9, f=0, gar="average",
+                       attack="none")
         st = init_flat_async_state(spec, params)
         assert st.bus.grads.shape[0] == 9   # clean mode: n_honest rows
 
@@ -408,7 +440,6 @@ class TestStalenessExcessAndRestore:
         the audit sweep also pins; a nonzero value here means the bus
         update and the delivery mask disagree about ages)."""
         from repro.configs import get_reduced
-        from repro.dist.train import DistByzantineSpec
         from repro.models import init_model
 
         cfg = get_reduced("llama3_2_3b")
@@ -419,8 +450,8 @@ class TestStalenessExcessAndRestore:
                                               cfg.vocab_size),
                  "labels": jax.random.randint(KEY, (n, b, s), 0,
                                               cfg.vocab_size)}
-        spec = DistByzantineSpec(f=0, gar="stale-krum", attack="none",
-                                 async_tau=tau, async_schedule="fixed")
+        spec = AggSpec(f=0, gar="stale-krum", attack="none",
+                       async_tau=tau, async_schedule="fixed")
         astep = jax.jit(make_async_train_step(cfg, spec, opt))
         state = init_async_state(spec, params, n)
         opt_state = opt.init(params)

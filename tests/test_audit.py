@@ -224,11 +224,24 @@ class TestStalenessBound:
             np.asarray(staleness_excess(bus, 5, tau)), [0, 0, 2, 0])
 
     def test_async_step_emits_the_metric(self):
-        from repro.dist.async_train import staleness_excess  # noqa: F401
-        import inspect
-        from repro.dist import async_train
-        src = inspect.getsource(async_train.make_async_train_step)
-        assert "staleness_excess" in src
+        from repro.configs import get_reduced
+        from repro.dist.async_train import (init_async_state,
+                                            make_async_train_step)
+        from repro.models import init_model
+        from repro.optim import get_optimizer
+
+        cfg = get_reduced("llama3_2_3b")
+        opt = get_optimizer("sgd", 1e-2)
+        spec = AggSpec(f=1, gar="stale-krum", attack="signflip", async_tau=2)
+        params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0),
+                                                   cfg))
+        batch = {"tokens": jax.ShapeDtypeStruct((7, 1, 8), jnp.int32),
+                 "labels": jax.ShapeDtypeStruct((7, 1, 8), jnp.int32)}
+        out = jax.eval_shape(make_async_train_step(cfg, spec, opt), params,
+                             jax.eval_shape(opt.init, params), batch,
+                             jax.eval_shape(lambda: init_async_state(
+                                 spec, params, 7)))
+        assert out[2]["staleness_excess"].shape == ()
 
 
 class TestLeeway:

@@ -96,7 +96,8 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     from repro.configs import get_reduced
     from repro.dist.mesh import make_host_mesh
     from repro.dist.sharding import param_shardings, batch_pspec
-    from repro.dist.train import DistByzantineSpec, make_train_step
+    from repro.agg import AggSpec
+    from repro.dist.train import make_train_step
     from repro.models import init_model
     from repro.optim import get_optimizer
 
@@ -106,7 +107,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     key = jax.random.PRNGKey(0)
     params = init_model(key, cfg)
     opt = get_optimizer("momentum", 1e-2)
-    spec = DistByzantineSpec(f=0, gar="bulyan-krum", attack="none")
+    spec = AggSpec(f=0, gar="bulyan-krum", attack="none")
     # n=4 workers < 4f+3 for f>0; use f=0 quorum-free bulyan? bulyan needs
     # n>=3 for f=0; theta=n, beta=n -> plain trimmed behaviour.
     step = make_train_step(cfg, spec, opt)

@@ -8,8 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.agg import AggSpec
 from repro.configs import ARCH_IDS, get_config, get_reduced
-from repro.dist.train import DistByzantineSpec, make_train_step
+from repro.dist.train import make_train_step
 from repro.models import forward, init_model
 from repro.models.attention import attention_blockwise, attention_naive
 from repro.models.ssm import ssd_chunked
@@ -54,8 +55,8 @@ class TestArchSmoke:
             batch["extra"] = jax.random.normal(
                 KEY, (n, b, cfg.vision_seq, cfg.d_model))
         opt = get_optimizer("sgd", 1e-2)
-        spec = DistByzantineSpec(f=f, gar="bulyan-krum",
-                                 attack="omniscient_linf")
+        spec = AggSpec(f=f, gar="bulyan-krum",
+                       attack="omniscient_linf")
         step = jax.jit(make_train_step(cfg, spec, opt))
         new_params, _, metrics = step(params, opt.init(params), batch)
         assert bool(jnp.isfinite(metrics["loss"]))

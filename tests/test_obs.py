@@ -195,7 +195,6 @@ def test_no_host_callbacks_in_compiled_telemetry_step():
     from repro.data import ByzantineBatcher
     from repro.models import simple
     from repro.optim import get_optimizer
-    from repro.training import ByzantineSpec
     from repro.training.trainer import (init_flat_agg_state,
                                         make_byzantine_step)
 
@@ -203,8 +202,8 @@ def test_no_host_callbacks_in_compiled_telemetry_step():
         return simple.classification_loss(
             simple.mnist_mlp_forward(params, x), y, params)
 
-    spec = ByzantineSpec(n_workers=9, f=2, gar="krum", attack="signflip",
-                         telemetry=True)
+    spec = AggSpec(n_workers=9, f=2, gar="krum", attack="signflip",
+                   telemetry=True)
     opt = get_optimizer("sgd", 0.05)
     params = simple.init_mnist_mlp(KEY)
     x, y = ByzantineBatcher("mnist", spec.n_honest, 8).batch(0)
@@ -223,15 +222,15 @@ def _run_trainer(gar, attack, n_workers, f, steps):
     from repro.data import ByzantineBatcher
     from repro.models import simple
     from repro.optim import get_optimizer
-    from repro.training import ByzantineSpec, ByzantineTrainer
+    from repro.training import ByzantineTrainer
 
     def loss_fn(params, x, y):
         return simple.classification_loss(
             simple.mnist_mlp_forward(params, x), y, params)
 
     kw = (("gar_name", gar),) if attack == "omniscient_lp" else ()
-    spec = ByzantineSpec(n_workers=n_workers, f=f, gar=gar, attack=attack,
-                         attack_kwargs=kw, telemetry=True)
+    spec = AggSpec(n_workers=n_workers, f=f, gar=gar, attack=attack,
+                   attack_kwargs=kw, telemetry=True)
     tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                           get_optimizer("sgd", 0.05), spec, seed=3)
     tr.run(ByzantineBatcher("mnist", spec.n_honest, 16), steps)
@@ -246,14 +245,14 @@ def test_telemetry_off_run_is_bitwise_identical():
         from repro.data import ByzantineBatcher
         from repro.models import simple
         from repro.optim import get_optimizer
-        from repro.training import ByzantineSpec, ByzantineTrainer
+        from repro.training import ByzantineTrainer
 
         def loss_fn(params, x, y):
             return simple.classification_loss(
                 simple.mnist_mlp_forward(params, x), y, params)
 
-        spec = ByzantineSpec(n_workers=9, f=2, gar="krum",
-                             attack="signflip", telemetry=telemetry)
+        spec = AggSpec(n_workers=9, f=2, gar="krum",
+                       attack="signflip", telemetry=telemetry)
         tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                               get_optimizer("sgd", 0.05), spec, seed=3)
         tr.run(ByzantineBatcher("mnist", spec.n_honest, 16), 3)
@@ -298,22 +297,21 @@ def test_metric_keys_consistent_across_flat_paths():
     from repro.data import ByzantineBatcher
     from repro.models import simple
     from repro.optim import get_optimizer
-    from repro.training import (AsyncByzantineTrainer, ByzantineSpec,
-                                ByzantineTrainer)
+    from repro.training import AsyncByzantineTrainer, ByzantineTrainer
 
     def loss_fn(params, x, y):
         return simple.classification_loss(
             simple.mnist_mlp_forward(params, x), y, params)
 
-    sync_spec = ByzantineSpec(n_workers=9, f=2, gar="krum",
-                              attack="signflip")
+    sync_spec = AggSpec(n_workers=9, f=2, gar="krum",
+                        attack="signflip")
     sync = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                             get_optimizer("sgd", 0.05), sync_spec)
     sync.run(ByzantineBatcher("mnist", sync_spec.n_honest, 8), 1)
     sync_keys = set(sync.history[0]) - {"step"}
 
-    async_spec = ByzantineSpec(n_workers=9, f=2, gar="krum",
-                               attack="signflip", async_tau=2)
+    async_spec = AggSpec(n_workers=9, f=2, gar="krum",
+                         attack="signflip", async_tau=2)
     a = AsyncByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                               get_optimizer("sgd", 0.05), async_spec)
     a.run(ByzantineBatcher("mnist", async_spec.n_honest, 8), 1)

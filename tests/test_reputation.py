@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.agg import (check_quorum, init_state, resolve_rule,
+from repro.agg import (AggSpec, check_quorum, init_state, resolve_rule,
                        reputation_scale, reputation_scores,
                        step_size_multiplier, tree_reputation_scores,
                        update_reputation)
@@ -296,8 +296,8 @@ class TestCheckpointAndTracing:
         assert s2.reputation.shape == (9,)
 
     def test_dist_init_agg_state_under_eval_shape(self):
-        from repro.dist.train import DistByzantineSpec, init_agg_state
-        spec = DistByzantineSpec(f=2, n_workers=7, gar="reputation-krum")
+        from repro.dist.train import init_agg_state
+        spec = AggSpec(f=2, n_workers=7, gar="reputation-krum")
         params = {"w": jax.ShapeDtypeStruct((4, 3), jnp.float32),
                   "b": jax.ShapeDtypeStruct((3,), jnp.float32)}
         st0 = jax.eval_shape(lambda: init_agg_state(spec, params, 7))
@@ -324,14 +324,14 @@ class TestTrainerIntegration:
         from repro.data import ByzantineBatcher
         from repro.models import simple
         from repro.optim import get_optimizer
-        from repro.training import ByzantineSpec, ByzantineTrainer
+        from repro.training import ByzantineTrainer
 
         def loss(params, x, y):
             return simple.classification_loss(
                 simple.mnist_mlp_forward(params, x), y, params)
 
-        spec = ByzantineSpec(n_workers=9, f=2, gar="reputation-krum",
-                             attack="signflip", rep_lr=0.5)
+        spec = AggSpec(n_workers=9, f=2, gar="reputation-krum",
+                       attack="signflip", rep_lr=0.5)
         tr = ByzantineTrainer(loss, simple.init_mnist_mlp(KEY),
                               get_optimizer("sgd", 0.05), spec)
         tr.run(ByzantineBatcher("mnist", spec.n_honest, 32), 5)

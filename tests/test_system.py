@@ -6,14 +6,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.agg import AggSpec
 from repro.configs import get_reduced
 from repro.data import ByzantineBatcher
 from repro.data.synthetic import lm_batches
-from repro.dist.train import DistByzantineSpec, make_loss_fn, make_train_step
+from repro.dist.train import make_loss_fn, make_train_step
 from repro.models import init_model
 from repro.models import simple
 from repro.optim import get_optimizer
-from repro.training import ByzantineSpec, ByzantineTrainer
+from repro.training import ByzantineTrainer
 
 KEY = jax.random.PRNGKey(0)
 
@@ -28,9 +29,9 @@ def test_headline_krum_vs_bulyan_one_round():
 
     devs = {}
     for gar in ("krum", "bulyan-krum"):
-        spec = ByzantineSpec(n_workers=15, f=3, gar=gar,
-                             attack="omniscient_lp",
-                             attack_kwargs=(("gar_name", "krum"),))
+        spec = AggSpec(n_workers=15, f=3, gar=gar,
+                       attack="omniscient_lp",
+                       attack_kwargs=(("gar_name", "krum"),))
         tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                               get_optimizer("sgd", 0.1), spec)
         tr.run(ByzantineBatcher("mnist", spec.n_honest, 83), 1)
@@ -44,8 +45,8 @@ def test_lm_training_under_attack_loss_decreases():
     cfg = get_reduced("llama3_2_3b")
     params = init_model(KEY, cfg)
     opt = get_optimizer("adam", 3e-3)
-    spec = DistByzantineSpec(f=1, gar="bulyan-krum",
-                             attack="omniscient_linf")
+    spec = AggSpec(f=1, gar="bulyan-krum",
+                   attack="omniscient_linf")
     step = jax.jit(make_train_step(cfg, spec, opt))
     state = opt.init(params)
     n, b, s = 7, 2, 64

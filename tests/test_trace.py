@@ -1,8 +1,9 @@
 """Tracing hooks (``repro.obs.trace``) in the train step and the serving
 engine.
 
-  1. **scopes** — every op of the train step that reads the step's inputs
-     sits under a program scope (``train/<phase>`` or ``agg``), and every
+  1. **scopes** — every op of the train step (synchronous or
+     asynchronous) that reads the step's inputs sits under a program
+     scope (``train/<phase>`` or ``agg``), and every
      aggregation op of the compiled step under exactly one of
      ``agg/gram``, ``agg/select`` and ``agg/coordinate``;
   2. **host spans** — under ``jax.profiler`` a tiny ensemble engine's
@@ -27,6 +28,7 @@ from jax.extend import core as jcore
 from repro.agg import AggSpec
 from repro.configs import get_reduced
 from repro.dist.serve_robust import replicate_params
+from repro.dist.async_train import init_async_state, make_async_train_step
 from repro.dist.train import make_train_step
 from repro.models import init_model
 from repro.obs import count_compiles, host_span
@@ -36,7 +38,7 @@ from repro.serving import Request, ServingEngine
 KEY = jax.random.PRNGKey(0)
 #: the program's scopes: the train step's phases and the aggregation
 PROGRAM_SCOPE = re.compile(
-    r"(^|/)(train/(grads|inject|optimizer|diagnostics)|agg)(/|$)")
+    r"(^|/)(train/(grads|inject|bus|optimizer|diagnostics)|agg)(/|$)")
 PHASES = ("gram", "select", "coordinate")
 
 
@@ -44,7 +46,7 @@ PHASES = ("gram", "select", "coordinate")
 # 1. scopes of the train step
 # ---------------------------------------------------------------------------
 
-def _train_step(gar):
+def _train_step(gar, asynchronous=False):
     cfg = get_reduced("qwen1_5_4b")
     opt = get_optimizer("adamw", 3e-4, weight_decay=0.01)
     params = init_model(KEY, cfg)
@@ -53,16 +55,24 @@ def _train_step(gar):
              "labels": jnp.ones((n, 1, 16), jnp.int32)}
     spec = AggSpec(f=1, gar=gar, attack="omniscient_linf",
                    attack_kwargs=(("margin", 3.0),))
-    return make_train_step(cfg, spec, opt), (params, opt.init(params), batch)
+    args = (params, opt.init(params), batch)
+    if asynchronous:
+        return (make_async_train_step(cfg, spec, opt),
+                args + (init_async_state(spec, params, n),))
+    return make_train_step(cfg, spec, opt), args
 
 
-@pytest.mark.parametrize("gar", ["bulyan-krum", "krum", "multikrum",
-                                 "trimmed_mean"])
-def test_every_train_step_op_carries_a_program_scope(gar):
-    """Ops that depend on the step's inputs carry a program scope.  The
-    rest are constants (rope tables, masks) that JAX hoists out of the
-    layer scan with a name stack of their own."""
-    step, args = _train_step(gar)
+@pytest.mark.parametrize("gar,asynchronous", [
+    pytest.param(gar, asynchronous, id=gar + ("-async" if asynchronous
+                                              else ""))
+    for asynchronous in (False, True)
+    for gar in ("bulyan-krum", "krum", "multikrum", "trimmed_mean")])
+def test_every_train_step_op_carries_a_program_scope(gar, asynchronous):
+    """Ops that depend on the step's inputs carry a program scope, in the
+    synchronous step and in the asynchronous one (its bus stage under
+    ``train/bus``).  The rest are constants (rope tables, masks) that
+    JAX hoists out of the layer scan with a name stack of their own."""
+    step, args = _train_step(gar, asynchronous)
     jaxpr = jax.make_jaxpr(step)(*args).jaxpr
     live = set(map(id, jaxpr.invars))
     unscoped = []

@@ -6,11 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.agg import AggSpec
 from repro.data import ByzantineBatcher
 from repro.data.synthetic import mnist_like
 from repro.models import simple
 from repro.optim import fading_lr, get_optimizer
-from repro.training import ByzantineSpec, ByzantineTrainer
+from repro.training import ByzantineTrainer
 
 KEY = jax.random.PRNGKey(1)
 
@@ -27,7 +28,7 @@ def _eval(params):
 
 
 def test_clean_training_learns():
-    spec = ByzantineSpec(n_workers=7, f=0, gar="average", attack="none")
+    spec = AggSpec(n_workers=7, f=0, gar="average", attack="none")
     tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                           get_optimizer("sgd", fading_lr(1.0, 10000)), spec)
     tr.run(ByzantineBatcher("mnist", 7, 32), 25)
@@ -37,9 +38,9 @@ def test_clean_training_learns():
 def test_attack_poisons_krum_but_not_bulyan_step0():
     devs = {}
     for gar in ("krum", "bulyan-krum"):
-        spec = ByzantineSpec(n_workers=15, f=3, gar=gar,
-                             attack="omniscient_lp",
-                             attack_kwargs=(("gar_name", "krum"),))
+        spec = AggSpec(n_workers=15, f=3, gar=gar,
+                       attack="omniscient_lp",
+                       attack_kwargs=(("gar_name", "krum"),))
         tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                               get_optimizer("sgd", 0.1), spec)
         tr.run(ByzantineBatcher("mnist", spec.n_honest, 64), 1)
@@ -49,9 +50,9 @@ def test_attack_poisons_krum_but_not_bulyan_step0():
 
 
 def test_byzantine_weight_metrics():
-    spec = ByzantineSpec(n_workers=15, f=3, gar="krum",
-                         attack="omniscient_lp",
-                         attack_kwargs=(("gar_name", "krum"),))
+    spec = AggSpec(n_workers=15, f=3, gar="krum",
+                   attack="omniscient_lp",
+                   attack_kwargs=(("gar_name", "krum"),))
     tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                           get_optimizer("sgd", 0.05), spec)
     tr.run(ByzantineBatcher("mnist", spec.n_honest, 64), 2)
@@ -59,10 +60,10 @@ def test_byzantine_weight_metrics():
 
 
 def test_bulyan_under_attack_still_learns():
-    spec = ByzantineSpec(n_workers=15, f=3, gar="bulyan-krum",
-                         attack="omniscient_lp",
-                         attack_kwargs=(("gar_name", "krum"),
-                                        ("coord", "top")))
+    spec = AggSpec(n_workers=15, f=3, gar="bulyan-krum",
+                   attack="omniscient_lp",
+                   attack_kwargs=(("gar_name", "krum"),
+                                  ("coord", "top")))
     tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                           get_optimizer("sgd", fading_lr(1.0, 10000)), spec)
     tr.run(ByzantineBatcher("mnist", spec.n_honest, 64), 25)
@@ -71,13 +72,13 @@ def test_bulyan_under_attack_still_learns():
 
 def test_quorum_validation():
     with pytest.raises(ValueError):
-        ByzantineSpec(n_workers=9, f=3, gar="bulyan-krum").validate()
+        AggSpec(n_workers=9, f=3, gar="bulyan-krum").validate()
 
 
 def test_attack_until_epoch_switches_off():
-    spec = ByzantineSpec(n_workers=15, f=3, gar="krum",
-                         attack="omniscient_lp",
-                         attack_kwargs=(("gar_name", "krum"),))
+    spec = AggSpec(n_workers=15, f=3, gar="krum",
+                   attack="omniscient_lp",
+                   attack_kwargs=(("gar_name", "krum"),))
     tr = ByzantineTrainer(loss_fn, simple.init_mnist_mlp(KEY),
                           get_optimizer("sgd", 0.05), spec)
     tr.run(ByzantineBatcher("mnist", spec.n_honest, 64), 4, attack_until=2)
