@@ -62,7 +62,9 @@ distance-based GAR, and it has two interchangeable implementations behind
 """
 from __future__ import annotations
 
+import functools
 import math
+import statistics
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -417,18 +419,36 @@ def _tree_coord_count(leaves) -> int:
     return sum(math.prod(l.shape[1:]) for l in leaves)
 
 
-def _tree_delta_bar(honest_leaves) -> jnp.ndarray:
+def _honest_moments(leaves, n_h: int):
+    """Per leaf, the f32 per-coordinate mean of the first ``n_h``
+    (honest) rows, and the sum over the leaf's coordinates of their
+    population std across those rows (ddof 0, as ``jnp.std``).
+
+    ``n_h`` is static and small, so both come from the same loaded rows,
+    one read of each leaf: the mean first, then the squared deviations
+    from it (never ``E[x^2] - E[x]^2``, which cancels where the workers
+    agree).  The means leave through an optimization barrier, so they
+    are written once and read back by whatever consumes them; otherwise
+    XLA folds the mean into the consumer's fusion and reads the honest
+    rows again there (and, where the consumer updates the stack in
+    place, copies them out first).
+    """
+    means, sums = [], []
+    for leaf in leaves:
+        rows = [leaf[i].astype(jnp.float32) for i in range(n_h)]
+        m = functools.reduce(jnp.add, rows) / n_h
+        var = functools.reduce(jnp.add, [(r - m) ** 2 for r in rows]) / n_h
+        means.append(m)
+        sums.append(jnp.sum(jnp.sqrt(var)))
+    return jax.lax.optimization_barrier((means, sums))
+
+
+def _delta_bar(sd_sums, leaves) -> jnp.ndarray:
     """Paper §B.1 ``delta_bar`` over the concatenated coordinate space,
-    accumulated per leaf: 2/sqrt(pi) * mean over coordinates of the
-    per-coordinate std across honest workers."""
-    total = jnp.zeros((), jnp.float32)
-    count = 0
-    for leaf in honest_leaves:
-        x = leaf.astype(jnp.float32)
-        sd = jnp.std(x, axis=0)
-        total = total + jnp.sum(sd)
-        count += math.prod(leaf.shape[1:])
-    return 2.0 / jnp.sqrt(jnp.pi) * total / max(count, 1)
+    from the per-leaf std sums of ``_honest_moments``: 2/sqrt(pi) * mean
+    over coordinates of the per-coordinate std across honest workers."""
+    total = sum(sd_sums, jnp.zeros((), jnp.float32))
+    return 2.0 / jnp.sqrt(jnp.pi) * total / max(_tree_coord_count(leaves), 1)
 
 
 def inject_byzantine(tree: Any, f: int, attack: str, key=None, *,
@@ -487,7 +507,12 @@ def inject_byzantine(tree: Any, f: int, attack: str, key=None, *,
     if key is None:
         key = jax.random.PRNGKey(0)
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    honest = [l[:n_h] for l in leaves]
+    # every attack but these reads the honest moments, all from one
+    # helper: one read of each leaf's honest rows
+    if attack in ("zero", "mimic", "random"):
+        means, sd_sums = [], []
+    else:
+        means, sd_sums = _honest_moments(leaves, n_h)
 
     def _broadcast(byz_one, leaf):
         """(…) per-leaf Byzantine value -> f stacked rows, leaf dtype."""
@@ -496,15 +521,13 @@ def inject_byzantine(tree: Any, f: int, attack: str, key=None, *,
 
     if attack == "signflip":
         s = 1.0 if scale is None else scale
-        byz = [_broadcast(-s * jnp.mean(h.astype(jnp.float32), axis=0),
-                          l) for h, l in zip(honest, leaves)]
+        byz = [_broadcast(-s * m, l) for m, l in zip(means, leaves)]
     elif attack == "zero":
         byz = [jnp.zeros((f,) + l.shape[1:], l.dtype) for l in leaves]
     elif attack == "mimic":
-        byz = [_broadcast(h[target], l) for h, l in zip(honest, leaves)]
+        byz = [_broadcast(l[:n_h][target], l) for l in leaves]
     elif attack == "ipm":
-        byz = [_broadcast(-eps * jnp.mean(h.astype(jnp.float32), axis=0), l)
-               for h, l in zip(honest, leaves)]
+        byz = [_broadcast(-eps * m, l) for m, l in zip(means, leaves)]
     elif attack == "random":
         s = 10.0 if scale is None else scale  # core.random_noise default
         byz = [s * jax.random.normal(jax.random.fold_in(key, j),
@@ -514,12 +537,13 @@ def inject_byzantine(tree: Any, f: int, attack: str, key=None, *,
         if z is None:
             s = (n // 2) + 1 - f
             phi = max(min((n - f - s) / float(n - f), 1.0 - 1e-6), 1e-6)
-            z = float(jax.scipy.special.ndtri(phi))
-        byz = [_broadcast(jnp.mean(h.astype(jnp.float32), axis=0)
-                          - z * jnp.std(h.astype(jnp.float32), axis=0), l)
-               for h, l in zip(honest, leaves)]
+            # in Python, not jnp: a jnp op under a trace is no constant
+            z = statistics.NormalDist().inv_cdf(phi)
+        # the per-coordinate std, about the helper's means
+        byz = [_broadcast(m - z * jnp.sqrt(jnp.mean(
+            (l[:n_h].astype(jnp.float32) - m) ** 2, axis=0)), l)
+               for m, l in zip(means, leaves)]
     elif attack in ("stale_replay", "slow_drift"):
-        means = [jnp.mean(h.astype(jnp.float32), axis=0) for h in honest]
         t = jnp.asarray(step if step is not None else 0, jnp.int32)
         prev_leaves = (jax.tree_util.tree_leaves(prev)
                        if prev is not None else [None] * len(leaves))
@@ -536,7 +560,7 @@ def inject_byzantine(tree: Any, f: int, attack: str, key=None, *,
                                   p.astype(l.dtype))
                    for m, l, p in zip(means, leaves, prev_leaves)]
         else:
-            db = _tree_delta_bar(honest)
+            db = _delta_bar(sd_sums, leaves)
             if direction == "anti":
                 es = [jnp.where(m == 0, 1.0, -jnp.sign(m)) for m in means]
             else:
@@ -552,30 +576,26 @@ def inject_byzantine(tree: Any, f: int, attack: str, key=None, *,
     elif attack == "reputation_burn":
         s = 3.0 if scale is None else scale
         t = jnp.asarray(step if step is not None else 0, jnp.int32)
-        byz = [_broadcast(jnp.where(t < build, 1.0, -s)
-                          * jnp.mean(h.astype(jnp.float32), axis=0), l)
-               for h, l in zip(honest, leaves)]
+        byz = [_broadcast(jnp.where(t < build, 1.0, -s) * m, l)
+               for m, l in zip(means, leaves)]
     elif attack == "colluding_majority":
         # one unit direction over the concatenated coordinate space,
         # normalized by the global norm: random per-leaf gaussians, or
         # (direction="anti") the negated honest mean — the
         # descent-reversing worst case, as in the flat attack
-        db = _tree_delta_bar(honest)
+        db = _delta_bar(sd_sums, leaves)
         if direction == "anti":
-            dirs = [-jnp.mean(h.astype(jnp.float32), axis=0)
-                    for h in honest]
+            dirs = [-m for m in means]
         else:
             dirs = [jax.random.normal(jax.random.fold_in(key, j),
                                       l.shape[1:], jnp.float32)
                     for j, l in enumerate(leaves)]
         norm = jnp.sqrt(sum(jnp.sum(e * e) for e in dirs)) + 1e-12
-        byz = [_broadcast(jnp.mean(h.astype(jnp.float32), axis=0)
-                          + eps * db * e / norm, l)
-               for h, e, l in zip(honest, dirs, leaves)]
+        byz = [_broadcast(m + eps * db * e / norm, l)
+               for m, e, l in zip(means, dirs, leaves)]
     elif attack in ("omniscient_linf", "omniscient_lp"):
         d = _tree_coord_count(leaves)
-        db = _tree_delta_bar(honest)
-        means = [jnp.mean(h.astype(jnp.float32), axis=0) for h in honest]
+        db = _delta_bar(sd_sums, leaves)
         # gamma None and "closed" both mean the §B closed form here (the
         # exact bisection only exists on the flat path); margin applies to
         # the estimate only — an explicit gamma is used verbatim
@@ -640,6 +660,7 @@ def inject_byzantine(tree: Any, f: int, attack: str, key=None, *,
     else:
         raise KeyError(f"unknown distributed attack {attack!r}")
 
-    out = [jnp.concatenate([l[:n_h], b], axis=0)
-           for l, b in zip(leaves, byz)]
+    # the f rows written in place: the honest rows stay where
+    # ``train/grads`` put them, bit for bit
+    out = [l.at[n_h:].set(b) for l, b in zip(leaves, byz)]
     return jax.tree_util.tree_unflatten(treedef, out)

@@ -1,14 +1,18 @@
 """Edge cases of the distributed robust-aggregation path that the main
 semantics tests (test_dist.py) don't cover: quorum violations, the f=0
-degenerate, single-leaf trees, mixed/bf16 dtypes, and the coordinate-phase
-window."""
+degenerate, single-leaf trees, mixed/bf16 dtypes, the coordinate-phase
+window, and the injection's honest moments against a float64 form."""
+import math
+import statistics
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import pytree as pt
-from repro.dist.robust import (coordinate_phase_nd, distributed_aggregate,
+from repro.dist.robust import (_delta_bar, _honest_moments,
+                               coordinate_phase_nd, distributed_aggregate,
                                inject_byzantine, pairwise_sq_dists_tree)
 
 KEY = jax.random.PRNGKey(11)
@@ -199,6 +203,95 @@ class TestInjectParity:
                                key=jax.random.PRNGKey(7))
         sd = float(np.std(np.asarray(out["a"][-f:])))
         assert 8.0 < sd < 12.0  # core.random_noise default scale=10.0
+
+
+def _byzantine_f64(leaves, f, attack, margin, coord):
+    """The attacks that read the honest moments, in plain float64 NumPy
+    with ``inject_byzantine``'s defaults: one Byzantine row per leaf."""
+    n_h = leaves[0].shape[0] - f
+    honest = [np.asarray(l[:n_h], np.float64) for l in leaves]
+    means = [h.mean(axis=0) for h in honest]
+    d = sum(m.size for m in means)
+    db = (2.0 / math.sqrt(math.pi)
+          * sum(h.std(axis=0).sum() for h in honest) / d)
+    if attack == "omniscient_linf":
+        return [m + margin * db for m in means]
+    if attack == "omniscient_lp":
+        # the closed-form gamma against Krum, p = 2 (paper §B)
+        g = math.sqrt(max((f + 1.0) / 2.0 - 0.5, 1e-9) * d) * db * margin
+        out, off = [], 0
+        for m in means:
+            e = np.zeros(m.size)
+            if 0 <= coord - off < m.size:
+                e[coord - off] = 1.0
+            out.append(m + g * e.reshape(m.shape))
+            off += m.size
+        return out
+    if attack == "alie":
+        n = n_h + f
+        s = n // 2 + 1 - f
+        z = statistics.NormalDist().inv_cdf(
+            max(min((n - f - s) / (n - f), 1.0 - 1e-6), 1e-6))
+        return [m - z * h.std(axis=0) for m, h in zip(means, honest)]
+    if attack == "colluding_majority":
+        norm = math.sqrt(sum((m * m).sum() for m in means)) + 1e-12
+        return [m - 0.5 * db * m / norm for m in means]
+    if attack == "slow_drift":
+        return [m + 0.5 * db for m in means]
+    assert attack == "signflip"
+    return [-m for m in means]
+
+
+_MOMENT_ATTACKS = {
+    "omniscient_linf": {"margin": 3.0},
+    "omniscient_lp": {"coord": 40, "margin": 0.5},
+    "alie": {},
+    "colluding_majority": {"direction": "anti"},
+    "slow_drift": {},
+    "signflip": {},
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("attack", sorted(_MOMENT_ATTACKS))
+def test_injection_moments_match_float64(attack, dtype):
+    """Every attack that reads the honest means or ``delta_bar`` takes
+    them from one read of the honest rows.  The honest rows come back
+    bit for bit, the Byzantine rows match a float64 form of the attack
+    (bf16 rows: up to the cast's rounding), and a leaf whose honest rows
+    all agree at a large value, where ``E[x^2] - E[x]^2`` would cancel
+    below zero, gives ``delta_bar >= 0`` and finite rows."""
+    n, f = 9, 2
+    kw = _MOMENT_ATTACKS[attack]
+    keys = jax.random.split(KEY, 3)
+    tree = {"w": jax.random.normal(keys[0], (n, 6, 4)).astype(dtype),
+            "b": (1.0 + jax.random.normal(keys[1], (n, 31))).astype(dtype),
+            "s": jax.random.normal(keys[2], (n,)).astype(dtype)}
+    run = jax.jit(lambda t: inject_byzantine(t, f, attack, **kw))
+    out = run(tree)
+    leaves = jax.tree_util.tree_leaves(tree)
+    got = jax.tree_util.tree_leaves(out)
+    want = _byzantine_f64(leaves, f, attack, kw.get("margin", 1.0),
+                          kw.get("coord", 0))
+    for l, g, w in zip(leaves, got, want):
+        assert g.dtype == l.dtype and g.shape == l.shape
+        np.testing.assert_array_equal(np.asarray(g[:n - f]),
+                                      np.asarray(l[:n - f]))
+        for row in np.asarray(g[n - f:].astype(jnp.float32), np.float64):
+            # rtol 1e-5 about the float64 form (atol for the means that
+            # cancel near zero); bf16 adds half an ulp of its rounding
+            bound = 1e-5 * np.abs(w) + 1e-6
+            if dtype == jnp.bfloat16:
+                bound = bound + 2.0 ** -8 * np.abs(row)
+            assert np.all(np.abs(row - w) <= bound), (
+                np.max(np.abs(row - w) - bound))
+
+    flat = {"c": jnp.full((n, 8, 16), 12345.679, dtype)}
+    _, sums = _honest_moments(jax.tree_util.tree_leaves(flat), n - f)
+    db = _delta_bar(sums, jax.tree_util.tree_leaves(flat))
+    assert 0.0 <= float(sums[0]) and 0.0 <= float(db) < 1e-2
+    assert bool(jnp.all(jnp.isfinite(
+        run(flat)["c"].astype(jnp.float32))))
 
 
 class TestCoordinatePhaseWindow:
